@@ -20,6 +20,8 @@ import traceback
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (common, fig7_baselines, fig8_recall, fig9_memory,
                         fig10_threshold, fig11_buckets, fig12_breakdown,
                         fig13_crossjoin, fig14_fragmentation, fig15_io,
@@ -109,6 +111,8 @@ def main() -> None:
                     help="write per-figure BENCH_<figure>.json records "
                          "into DIR (perf-trajectory pipeline)")
     args = ap.parse_args()
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     fingerprint = None
     if args.json_out:

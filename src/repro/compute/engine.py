@@ -26,14 +26,16 @@ pair extraction happens (``JoinConfig.compute_mode``):
     work until batch k's results are collected at the head of flush k+1 —
     so the entire enqueue/walk/staging of batch k+1 overlaps batch k's
     kernel (``d2h_overlap_s``). The kernel returns compacted
-    (row, col, distance) triples via an on-device mask → prefix-sum →
+    (row, col, d²) triples via an on-device mask → prefix-sum →
     gather compaction, so the host never materializes an (E, cap, cap)
-    mask and never re-derives sqrt distances.
+    mask.
 
 Distance parity: both modes take d² from the same jitted program and
-apply an IEEE float32 sqrt (numpy on host, XLA on device) — bitwise
-identical. Pair order parity: the compaction scatter walks the mask in
-row-major flat order, exactly ``np.nonzero``'s order.
+apply numpy's IEEE float32 sqrt on the host — bitwise identical. (A
+TPU's float32 sqrt is not correctly rounded, so a sqrt on the device
+would differ from the host engine's in the last bit.) Pair order
+parity: the compaction scatter walks the mask in row-major flat order,
+exactly ``np.nonzero``'s order.
 
 The compaction capacity (pairs per edge) adapts: a batch whose densest
 edge overflows the current capacity is re-compacted from its still-
@@ -51,6 +53,7 @@ import numpy as np
 
 from repro.compute.slab_pool import DeviceSlabPool
 from repro.kernels import ops as kops
+from repro.kernels import ref
 from repro.obs import get_tracer
 
 PAIR_CAP_INIT = 1024  # initial per-edge compaction capacity (pairs)
@@ -68,9 +71,10 @@ def compact_pairs(d2: jax.Array, mask: jax.Array, na: jax.Array,
     d2/mask: (E, M, N); na/nb: (E,) int32 live-row counts (0 kills a
     padded batch lane); intra: (E,) bool — keep strictly-upper pairs only
     (self-join bucket-vs-itself edges). Returns (counts (E,) int32,
-    rows (E, k_cap) int32, cols (E, k_cap) int32, dists (E, k_cap) f32);
+    rows (E, k_cap) int32, cols (E, k_cap) int32, d2 (E, k_cap) f32);
     entries past an edge's count are zeros, pairs past ``k_cap`` are
     dropped (the caller detects counts > k_cap and re-compacts larger).
+    The caller takes the sqrt on the host (module docstring: parity).
     """
     E, M, N = d2.shape
     rows = jax.lax.broadcasted_iota(jnp.int32, (M, N), 0)
@@ -96,7 +100,7 @@ def compact_pairs(d2: jax.Array, mask: jax.Array, na: jax.Array,
     out_d2 = jnp.where(
         valid, jnp.take_along_axis(d2.reshape(E, M * N), order, axis=1),
         0.0)
-    return counts, out_r, out_c, jnp.sqrt(out_d2)
+    return counts, out_r, out_c, out_d2
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "k_cap", "use_pallas"))
@@ -130,10 +134,9 @@ def query_verify_compact(q_block: jax.Array, qidx: jax.Array, nq,
     ONCE and each probed bucket's verify gathers its member rows from it.
     ``qidx`` is pow2-padded (bounded recompiles); ``nq`` live entries —
     padded rows repeat query 0 and are masked out by the row count.
-    Returns compacted (counts (1,), q-rows, cols, distances) against the
+    Returns compacted (counts (1,), q-rows, cols, d²) against the
     (capacity, dim) bucket slab."""
     qs = jnp.take(q_block, qidx, axis=0)             # (Qp, d)
-    from repro.kernels import ref
     d2 = ref.pairwise_l2(qs, slab)[None]             # (1, Qp, cap)
     na = jnp.reshape(nq, (1,)).astype(jnp.int32)
     nb = jnp.full((1,), slab.shape[0], jnp.int32)
@@ -448,7 +451,7 @@ class DeviceVerifyEngine(_EngineBase):
             self.pool.harvest(b, out[4 + side][lane])
         rows = np.asarray(out[1])
         cols = np.asarray(out[2])
-        dists = np.asarray(out[3])
+        dists = np.sqrt(np.asarray(out[3]))
         fetched = counts.nbytes + rows.nbytes + cols.nbytes + dists.nbytes
         self._stat("d2h_bytes", fetched)
         self._charge_link(fetched)

@@ -25,13 +25,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ref import MATMUL_PRECISION
+
 
 @partial(jax.jit, static_argnames=("k",))
 def _topk_neg_dist(queries: jax.Array, centers: jax.Array,
                    center_sq: jax.Array, k: int):
     """Top-k nearest (squared L2) centers per query via one matmul."""
     qsq = jnp.sum(queries * queries, axis=1, keepdims=True)
-    d2 = qsq - 2.0 * queries @ centers.T + center_sq[None, :]
+    d2 = (qsq - 2.0 * jnp.matmul(queries, centers.T,
+                                 precision=MATMUL_PRECISION)
+          + center_sq[None, :])
     neg, idx = jax.lax.top_k(-d2, k)
     return -neg, idx
 
@@ -39,7 +43,9 @@ def _topk_neg_dist(queries: jax.Array, centers: jax.Array,
 @jax.jit
 def _nearest(queries: jax.Array, centers: jax.Array, center_sq: jax.Array):
     qsq = jnp.sum(queries * queries, axis=1, keepdims=True)
-    d2 = qsq - 2.0 * queries @ centers.T + center_sq[None, :]
+    d2 = (qsq - 2.0 * jnp.matmul(queries, centers.T,
+                                 precision=MATMUL_PRECISION)
+          + center_sq[None, :])
     idx = jnp.argmin(d2, axis=1)
     return jnp.take_along_axis(d2, idx[:, None], axis=1)[:, 0], idx
 

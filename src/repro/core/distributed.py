@@ -65,6 +65,16 @@ def verify_edges_compact(slab: jax.Array, edges: jax.Array, na: jax.Array,
     return compact_pairs(d2, d2 <= eps2, na, nb, intra, k_cap)
 
 
+def with_auto_axes(mesh: jax.sharding.Mesh) -> jax.sharding.Mesh:
+    """The same devices and axis names with Auto axis types.
+
+    ``jax.make_mesh`` gives Explicit axes, under which a gather by
+    data-sharded edge indices must name its output sharding. With Auto
+    axes the compiler keeps the window slab replicated and shards the
+    gathered operands with their edges."""
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names)
+
+
 @dataclasses.dataclass
 class Superstep:
     bucket_ids: np.ndarray   # (W,) global bucket ids in this window
@@ -138,7 +148,7 @@ class DistributedJoin:
         self.store = store
         self.meta = meta
         self.config = config
-        self.mesh = mesh
+        self.mesh = with_auto_axes(mesh) if mesh is not None else None
         self.cap = resolve_bucket_capacity(config, meta.sizes)
         self.cache_buckets = resolve_cache_buckets(config, self.cap,
                                                    store.dim)
@@ -147,6 +157,8 @@ class DistributedJoin:
         self.loads = 0
         self.hits = 0
         self.prefetched = 0  # window w+1 loads issued under w's verify
+        self.dispatches = 0  # verify programs issued (chunks of windows)
+        self.overflows = 0   # chunks re-sent at a larger pair capacity
         # compute_mode="device": per-bucket device slabs persist across
         # supersteps (evicted on the host keep-set), so consecutive
         # windows re-transfer only their *new* buckets instead of
@@ -211,58 +223,89 @@ class DistributedJoin:
                     self.loads += 1
                     self.prefetched += 1
 
-    def _dispatch_compact(self, slab, edges, entries, eps2, sharding):
-        """Issue the compacted verify for one superstep (async). Edge
-        count pads to the next pow2 (bounded recompiles) and, under a
-        mesh, to a shard multiple; pad lanes carry na = nb = 0 so the
-        compaction masks them out entirely."""
-        E = edges.shape[0]
-        Ep = self._next_pow2(E)
-        if sharding is not None:
-            Ep = _round_up(Ep, self.mesh.shape["data"])
-        pe = edges
-        if Ep != E:
-            pe = np.concatenate([edges, np.zeros((Ep - E, 2), edges.dtype)])
+    def _edge_chunks(self, edges, sharding):
+        """A window's edges as dispatches of at most ``verify_batch``
+        edges per shard, in both compute modes: each dispatch holds
+        (E, cap, cap) distance temporaries, which for a whole window's
+        edges can outgrow device memory. A chunk pads to the next pow2
+        (bounded recompiles) and, under a mesh, to a shard multiple; pad
+        lanes point at slab 0 and their results are dropped. Yields
+        (chunk edges, padded device edges)."""
+        shards = self.mesh.shape["data"] if sharding is not None else 1
+        chunk = self.config.verify_batch * shards
+        for c0 in range(0, edges.shape[0], chunk):
+            ce = edges[c0:c0 + chunk]
+            Ep = _round_up(self._next_pow2(ce.shape[0]), shards)
+            edges_dev = jnp.asarray(np.concatenate(
+                [ce, np.zeros((Ep - ce.shape[0], 2), ce.dtype)]))
+            if sharding is not None:
+                edges_dev = jax.device_put(edges_dev, sharding)
+            yield ce, edges_dev
+
+    def _dispatch_compact(self, slab, ce, edges_dev, entries, eps2):
+        """Issue the compacted verify of one chunk (async) at the current
+        pair capacity. Pad lanes carry na = nb = 0 so the compaction
+        masks them out entirely. The handle keeps the capacity the chunk
+        was sent at: a raise by an earlier chunk's overflow must not
+        hide this one's."""
+        Ep, E = edges_dev.shape[0], ce.shape[0]
         rowc = np.array([e[2] for e in entries], np.int32)
         na = np.zeros(Ep, np.int32)
         nb = np.zeros(Ep, np.int32)
-        na[:E] = rowc[edges[:, 0]]
-        nb[:E] = rowc[edges[:, 1]]
+        na[:E] = rowc[ce[:, 0]]
+        nb[:E] = rowc[ce[:, 1]]
         intra = np.zeros(Ep, bool)
-        intra[:E] = edges[:, 0] == edges[:, 1]
-        edges_dev = jnp.asarray(pe)
-        if sharding is not None:
-            edges_dev = jax.device_put(edges_dev, sharding)
-        out = verify_edges_compact(slab, edges_dev, jnp.asarray(na),
-                                   jnp.asarray(nb), jnp.asarray(intra),
-                                   eps2, self._pair_cap)
-        return out, na, nb, intra, edges_dev
+        intra[:E] = ce[:, 0] == ce[:, 1]
+        args = (slab, edges_dev, jnp.asarray(na), jnp.asarray(nb),
+                jnp.asarray(intra))
+        k_cap = self._pair_cap
+        return ce, args, k_cap, verify_edges_compact(*args, eps2, k_cap)
 
-    def _extract_compact(self, handle, slab, edges, entries, eps2):
-        """Fetch a superstep's compacted pairs (+ distances); on per-edge
-        capacity overflow re-dispatch at the next pow2 (sticky for later
-        steps)."""
-        out, na, nb, intra, edges_dev = handle
-        E = edges.shape[0]
-        counts = np.asarray(out[0])
-        top = int(counts[:E].max()) if E else 0
-        if top > self._pair_cap:
-            self._pair_cap = min(self._next_pow2(top), self.cap * self.cap)
-            out = verify_edges_compact(slab, edges_dev, jnp.asarray(na),
-                                       jnp.asarray(nb), jnp.asarray(intra),
-                                       eps2, self._pair_cap)
-            counts = np.asarray(out[0])
-        rows_c = np.asarray(out[1])
-        cols_c = np.asarray(out[2])
-        dist_c = np.asarray(out[3])
+    def _extract_compact(self, handles, entries, eps2):
+        """Fetch a superstep's compacted pairs (+ distances), chunk by
+        chunk in edge order. A chunk whose densest edge holds more pairs
+        than the capacity it was sent at is re-sent at the next pow2;
+        the raise is sticky for later chunks and steps."""
         res, res_d = [], []
-        for ei, (a, b) in enumerate(edges):
-            k = int(counts[ei])
-            if k:
-                ida, idb = entries[a][1], entries[b][1]
-                res.append(np.stack([ida[rows_c[ei, :k]],
-                                     idb[cols_c[ei, :k]]], axis=1))
-                res_d.append(dist_c[ei, :k].astype(np.float32))
+        for ce, args, k_cap, out in handles:
+            counts = np.asarray(out[0])
+            top = int(counts[:ce.shape[0]].max())
+            if top > k_cap:
+                self.overflows += 1
+                self._pair_cap = max(self._pair_cap, min(
+                    self._next_pow2(top), self.cap * self.cap))
+                out = verify_edges_compact(*args, eps2, self._pair_cap)
+                counts = np.asarray(out[0])
+            rows_c = np.asarray(out[1])
+            cols_c = np.asarray(out[2])
+            dist_c = np.sqrt(np.asarray(out[3]))
+            for ei, (a, b) in enumerate(ce):
+                k = int(counts[ei])
+                if k:
+                    ida, idb = entries[a][1], entries[b][1]
+                    res.append(np.stack([ida[rows_c[ei, :k]],
+                                         idb[cols_c[ei, :k]]], axis=1))
+                    res_d.append(dist_c[ei, :k].astype(np.float32))
+        return res, res_d
+
+    @staticmethod
+    def _extract_host(handles, entries):
+        """Pairs (+ distances) from each chunk's full mask and d²."""
+        res, res_d = [], []
+        for ce, out in handles:
+            mask = np.asarray(out[1])
+            d2 = np.asarray(out[2])
+            for ei, (a, b) in enumerate(ce):
+                na, nb = entries[a][2], entries[b][2]
+                m = mask[ei][:na, :nb]
+                if a == b:
+                    m = np.triu(m, k=1)
+                rows, cols = np.nonzero(m)
+                if rows.size:
+                    ida, idb = entries[a][1], entries[b][1]
+                    res.append(np.stack([ida[rows], idb[cols]], axis=1))
+                    res_d.append(np.sqrt(d2[ei][:na, :nb][rows, cols]
+                                         ).astype(np.float32))
         return res, res_d
 
     def fingerprint(self) -> str:
@@ -336,7 +379,7 @@ class DistributedJoin:
                                     edges=int(edges.shape[0]))
             step_span.__enter__()
             entries = [self._fetch(int(b)) for b in step.bucket_ids]
-            E = edges.shape[0]
+            chunks = self._edge_chunks(edges, sharding)
             if self._dev_pool is not None:
                 # device mode: the window slab is a stack of per-bucket
                 # slabs already resident on-device (one transfer per host
@@ -351,23 +394,14 @@ class DistributedJoin:
                 for wi, b in enumerate(step.bucket_ids):
                     if self._dev_pool.needs_harvest(int(b)):
                         self._dev_pool.harvest(int(b), slab[wi])
-                out = self._dispatch_compact(slab, edges, entries,
-                                             eps2, sharding)
+                handles = [self._dispatch_compact(slab, ce, edges_dev,
+                                                  entries, eps2)
+                           for ce, edges_dev in chunks]
             else:
                 slab = jnp.asarray(np.stack([e[0] for e in entries]))
-                # pad edge count to shard evenly; padding repeats edge 0
-                # whose results are sliced off
-                pe = edges
-                if sharding is not None:
-                    n_shards = self.mesh.shape["data"]
-                    Ep = _round_up(E, n_shards)
-                    if Ep != E:
-                        pe = np.concatenate(
-                            [edges, np.repeat(edges[:1], Ep - E, axis=0)])
-                    edges_dev = jax.device_put(jnp.asarray(pe), sharding)
-                else:
-                    edges_dev = jnp.asarray(pe)
-                out = verify_edges(slab, edges_dev, eps2)
+                handles = [(ce, verify_edges(slab, edges_dev, eps2))
+                           for ce, edges_dev in chunks]
+            self.dispatches += len(handles)
             # verify is dispatched asynchronously: pull window w+1's
             # missing buckets from disk while this window's kernel runs
             if si + 1 < len(steps):
@@ -378,24 +412,10 @@ class DistributedJoin:
                 for a, b in edges)
             if self._dev_pool is not None:
                 step_pairs, step_dists = self._extract_compact(
-                    out, slab, edges, entries, eps2)
+                    handles, entries, eps2)
             else:
-                mask = np.asarray(out[1])[:E]
-                d2 = np.asarray(out[2])[:E]
-                step_pairs, step_dists = [], []
-                for ei, (a, b) in enumerate(edges):
-                    na, nb = entries[a][2], entries[b][2]
-                    m = mask[ei][:na, :nb]
-                    if a == b:
-                        m = np.triu(m, k=1)
-                    rows, cols = np.nonzero(m)
-                    if rows.size:
-                        ida, idb = entries[a][1], entries[b][1]
-                        step_pairs.append(
-                            np.stack([ida[rows], idb[cols]], axis=1))
-                        step_dists.append(
-                            np.sqrt(d2[ei][:na, :nb][rows, cols]
-                                    ).astype(np.float32))
+                step_pairs, step_dists = self._extract_host(handles,
+                                                            entries)
             pairs_out.extend(step_pairs)
             dists_out.extend(step_dists)
             if checkpointer is not None:
@@ -425,7 +445,8 @@ class DistributedJoin:
         info = {"supersteps": len(steps), "host_loads": self.loads,
                 "host_hits": self.hits, "prefetched_buckets": self.prefetched,
                 "distance_computations": dc, "dists": dists,
-                "watermark_rows": watermark}
+                "watermark_rows": watermark,
+                "verify_dispatches": self.dispatches}
         if resume_from is not None:
             info["resumed_at"] = start_si
             info["restore_s"] = restore_s
@@ -435,4 +456,5 @@ class DistributedJoin:
             info["h2d_transfers"] = self._dev_pool.transfers
             info["device_slab_hits"] = self._dev_pool.hits
             info["h2d_bytes"] = self._dev_pool.h2d_bytes
+            info["compact_overflows"] = self.overflows
         return pairs, info

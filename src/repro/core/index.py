@@ -962,7 +962,7 @@ class DiskJoinIndex:
                 return
             qrows = np.asarray(r)[0, :k]
             cols = np.asarray(c)[0, :k]
-            dists = np.asarray(d)[0, :k]
+            dists = np.sqrt(np.asarray(d)[0, :k])
             lids = ids_[:n]
             for row in np.unique(qrows):
                 sel = qrows == row
@@ -1030,6 +1030,10 @@ class DiskJoinIndex:
         misses: list[int] = []
         for b in buckets:
             if skip is not None and skip(b):
+                with self._warm_lock:
+                    miss = b not in self._warm
+                if miss:  # cancelled before its read was even queued
+                    self.stats.add("midwave_skipped_reads", 1)
                 continue
             with self._warm_lock:
                 ent = self._warm.get(b)

@@ -80,13 +80,20 @@ def brute_force_pairs(x: np.ndarray, epsilon: float,
 
 def epsilon_for_avg_neighbors(x: np.ndarray, k: int,
                               sample: int = 512, seed: int = 0) -> float:
-    """Calibrate ε so the average #ε-neighbors per vector ≈ k."""
+    """Calibrate ε so the average #ε-neighbors per vector ≈ k.
+
+    The sample's distance rows are taken 64 at a time, so memory stays at
+    64 × n doubles at millions of vectors."""
     rng = np.random.default_rng(seed)
     n = x.shape[0]
     idx = rng.choice(n, size=min(sample, n), replace=False)
-    q = x[idx].astype(np.float64)
-    sq = np.sum(x.astype(np.float64) ** 2, axis=1)
-    d2 = (np.sum(q * q, axis=1)[:, None] - 2.0 * q @ x.T + sq[None, :])
-    d2 = np.maximum(d2, 0)
-    kth = np.sort(d2, axis=1)[:, min(k, n - 1)]  # k-th neighbor (excl. self)
-    return float(np.sqrt(np.median(kth)))
+    x64 = x.astype(np.float64)
+    sq = np.sum(x64 ** 2, axis=1)
+    kk = min(k, n - 1)  # k-th neighbor (excl. self)
+    kth = []
+    for i0 in range(0, idx.size, 64):
+        q = x64[idx[i0:i0 + 64]]
+        d2 = (np.sum(q * q, axis=1)[:, None] - 2.0 * q @ x64.T
+              + sq[None, :])
+        kth.append(np.partition(np.maximum(d2, 0), kk, axis=1)[:, kk])
+    return float(np.sqrt(np.median(np.concatenate(kth))))

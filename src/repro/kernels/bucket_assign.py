@@ -4,7 +4,11 @@ Scan-2 hot loop of bucketization: for a block of vectors X (M, d) and the
 center table C (B, d), find argmin_b d²(x, c_b) per row. Tiling: grid
 (M/bm, B/bb); the running (min, argmin) pair lives in the output refs across
 the center-tile loop (out block index ignores the center axis), so the
-(bm, bb) distance tile never round-trips to HBM — only 2·bm values do.
+(bm, bb) distance tile never round-trips to HBM.
+
+The outputs are lane-dense (M, 128) arrays whose lanes all hold the row's
+value; the wrapper keeps lane 0. A 1-D (bm,) output block would need a
+tiling Mosaic does not share with XLA's for a long 1-D array.
 
 d is kept whole per tile (embedding dims ≤ a few K fit VMEM comfortably:
 128 rows × 1536 dims × 4 B = 768 KiB).
@@ -17,9 +21,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import MATMUL_PRECISION
 
 DEFAULT_BM = 128
 DEFAULT_BB = 128
+LANES = 128
 
 
 def _assign_kernel(x_ref, c_ref, mind2_ref, idx_ref, *, bb: int):
@@ -32,14 +38,18 @@ def _assign_kernel(x_ref, c_ref, mind2_ref, idx_ref, *, bb: int):
 
     x = x_ref[...].astype(jnp.float32)           # (bm, d)
     c = c_ref[...].astype(jnp.float32)           # (bb, d)
-    d2 = (jnp.sum(x * x, axis=1)[:, None]
+    d2 = (jnp.sum(x * x, axis=1, keepdims=True)
           - 2.0 * jax.lax.dot_general(
               x, c, (((1,), (1,)), ((), ())),
-              preferred_element_type=jnp.float32)
+              preferred_element_type=jnp.float32,
+              precision=MATMUL_PRECISION)
           + jnp.sum(c * c, axis=1)[None, :])     # (bm, bb)
-    tile_min = jnp.min(d2, axis=1)
-    tile_arg = jnp.argmin(d2, axis=1).astype(jnp.int32) + j * bb
+    tile_min = jnp.min(d2, axis=1, keepdims=True)
+    tile_arg = jnp.argmin(d2, axis=1).astype(jnp.int32)[:, None] + j * bb
 
+    shape = mind2_ref.shape
+    tile_min = jnp.broadcast_to(tile_min, shape)
+    tile_arg = jnp.broadcast_to(tile_arg, shape)
     better = tile_min < mind2_ref[...]
     mind2_ref[...] = jnp.where(better, tile_min, mind2_ref[...])
     idx_ref[...] = jnp.where(better, tile_arg, idx_ref[...])
@@ -52,7 +62,7 @@ def bucket_assign(x: jax.Array, centers: jax.Array,
     """(M, d) × (B, d) → (min_d2 (M,) f32, argmin (M,) int32).
 
     M and B must be multiples of bm/bb (callers pad; padded centers must be
-    at +inf-distance — use `ops.bucket_assign`, which pads with +1e30 rows).
+    far away — use `ops.bucket_assign`, which pads with 1e15 rows).
     """
     m, d = x.shape
     b, _ = centers.shape
@@ -69,13 +79,13 @@ def bucket_assign(x: jax.Array, centers: jax.Array,
             pl.BlockSpec((bb, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec((bm, LANES), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, LANES), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.float32),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct((m, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((m, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(x, centers)
-    return mind2, idx
+    return mind2[:, 0], idx[:, 0]

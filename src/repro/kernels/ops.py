@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Every op pads its inputs to kernel block multiples, dispatches to the Pallas
-kernel on TPU (interpret mode elsewhere — the kernel body runs in Python on
-CPU for correctness), or to the pure-jnp reference when ``use_pallas`` is
+kernel compiled on TPU (interpreted on the CPU backend, for tests; any other
+backend is an error), or to the pure-jnp reference when ``use_pallas`` is
 off, and strips padding from the result. The DiskJoin executor and the model
 stack call only this layer.
 """
@@ -22,6 +22,18 @@ from repro.kernels import ref
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_mode() -> bool:
+    """Compiled on TPU, interpreted on CPU; never a silent interpreter
+    run on an accelerator the kernels were not written for."""
+    if on_tpu():
+        return False
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted "
+                       f"on CPU, not on backend {backend!r}")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -52,7 +64,7 @@ def pairwise_l2_threshold(a, b, eps: float, *, use_pallas: bool = False,
     ap = jnp.pad(a, ((0, mp - m), (0, dp - d)))
     bp = jnp.pad(b, ((0, np_ - n), (0, dp - d)))
     d2, mask = _pairwise_kernel.pairwise_l2_threshold(
-        ap, bp, eps2, interpret=not on_tpu())
+        ap, bp, eps2, interpret=interpret_mode())
     return d2[:m, :n], mask[:m, :n].astype(bool)
 
 
@@ -88,7 +100,7 @@ def verify_pairs_batch(u, v, eps: float, *, use_pallas: bool = False,
         u = jnp.pad(u, ((0, 0), (0, mp - m), (0, 0)), constant_values=1e15)
         v = jnp.pad(v, ((0, 0), (0, mp - m), (0, 0)), constant_values=1e15)
     d2, mask = _pairwise_kernel.pairwise_l2_threshold_batched(
-        u, v, eps2, interpret=not on_tpu())
+        u, v, eps2, interpret=interpret_mode())
     if mp != m:
         d2, mask = d2[:, :m, :m], mask[:, :m, :m]
     return d2, mask.astype(bool)
@@ -113,7 +125,7 @@ def bucket_assign(x, centers, *, use_pallas: bool = True, block: int = 128):
         far = jnp.full((bp - b, d), 1e15, jnp.float32)
         cp = jnp.concatenate([centers, far], axis=0)
     mind2, idx = _assign_kernel.bucket_assign(xp, cp,
-                                              interpret=not on_tpu())
+                                              interpret=interpret_mode())
     return mind2[:m], idx[:m]
 
 
@@ -149,7 +161,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             return ref.attention(q, k, v, causal=causal, scale=scale)
     out = _flash_kernel.flash_attention(qf, kf, vf, causal=causal,
                                         scale=scale, bq=bq, bkv=bkv,
-                                        interpret=not on_tpu())
+                                        interpret=interpret_mode())
     return out[:, :S, :].reshape(B, H, S, D)
 
 

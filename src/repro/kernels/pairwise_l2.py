@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import MATMUL_PRECISION
+
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
@@ -44,7 +46,8 @@ def _pairwise_kernel(a_ref, b_ref, d2_ref, mask_ref, *, eps2: float,
     # |a_k|^2 and |b_k|^2 per slice is exact since norms decompose over k.
     acc = d2_ref[...]
     acc += -2.0 * jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=MATMUL_PRECISION)
     acc += jnp.sum(a * a, axis=1)[:, None]
     acc += jnp.sum(b * b, axis=1)[None, :]
     d2_ref[...] = acc
@@ -69,7 +72,8 @@ def _pairwise_kernel_batched(a_ref, b_ref, d2_ref, mask_ref, *, eps2: float,
     b = b_ref[0].astype(jnp.float32)            # (bn, bk)
     acc = d2_ref[0]
     acc += -2.0 * jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=MATMUL_PRECISION)
     acc += jnp.sum(a * a, axis=1)[:, None]
     acc += jnp.sum(b * b, axis=1)[None, :]
     d2_ref[0] = acc

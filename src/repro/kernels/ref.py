@@ -4,6 +4,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Full f32 products for every distance matmul. At DEFAULT precision a TPU
+# multiplies f32 operands in one bf16 pass; the a² − 2ab + b² expansion
+# then moves d² by far more than the gap between pairs near ε.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def pairwise_l2(a: jax.Array, b: jax.Array) -> jax.Array:
     """Squared L2 distance matrix: (M, d) × (N, d) → (M, N) float32."""
@@ -11,7 +16,8 @@ def pairwise_l2(a: jax.Array, b: jax.Array) -> jax.Array:
     b = b.astype(jnp.float32)
     a2 = jnp.sum(a * a, axis=1, keepdims=True)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)
-    d2 = a2 - 2.0 * (a @ b.T) + b2.T
+    ab = jnp.matmul(a, b.T, precision=MATMUL_PRECISION)
+    d2 = a2 - 2.0 * ab + b2.T
     return jnp.maximum(d2, 0.0)
 
 

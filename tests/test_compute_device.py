@@ -251,8 +251,7 @@ class TestCompaction:
             assert counts[e] == k
             assert np.array_equal(r[e, :k], rows)
             assert np.array_equal(c[e, :k], cols)
-            np.testing.assert_array_equal(
-                d[e, :k], np.sqrt(d2[e][rows, cols]))
+            np.testing.assert_array_equal(d[e, :k], d2[e][rows, cols])
         assert counts[2] == 0  # na = 0 kills the padded lane
 
     def test_overflow_reports_true_count(self):
@@ -386,6 +385,26 @@ class TestDistributedDevice:
         assert info["prefetched_buckets"] > 0
         # prefetched loads are a subset of total loads (never extra I/O)
         assert info["prefetched_buckets"] <= info["host_loads"]
+
+    def test_overflow_in_one_chunk_keeps_later_chunks_whole(self, tmp_path):
+        """A superstep's chunks are all sent before any is read. When one
+        overflows and raises the pair capacity, a later chunk sent at the
+        old capacity must be re-sent, not read truncated."""
+        from repro.core import JoinConfig
+        from repro.core.distributed import DistributedJoin
+
+        bs, meta, graph, cfg = self._setup(tmp_path, 150_000)
+        cfg["verify_batch"] = 1  # one edge per chunk
+        ph, ih = DistributedJoin(bs, meta, JoinConfig(**cfg)).run(graph)
+        dj = DistributedJoin(bs, meta,
+                             JoinConfig(compute_mode="device", **cfg))
+        dj._pair_cap = 1  # the first chunk to hold two pairs overflows
+        pd, idv = dj.run(graph)
+        assert idv["verify_dispatches"] > 3 * idv["supersteps"]
+        assert ih["verify_dispatches"] == idv["verify_dispatches"]
+        assert idv["compact_overflows"] >= 1
+        assert np.array_equal(ph, pd)
+        assert np.array_equal(ih["dists"], idv["dists"])
 
 
 # ---------------------------------------------------------------------------

@@ -91,3 +91,16 @@ def test_extract_pairs_upper_triangle():
     pairs, dists = ops.extract_pairs(d2, mask, ids, ids, upper_triangle=True)
     assert pairs.tolist() == [[7, 9]]
     np.testing.assert_allclose(dists, [1.0])
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False),
+                                            ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, expect):
+    """Kernels compile on TPU, interpret on CPU, and refuse any other
+    backend instead of silently running the interpreter there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expect is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is expect

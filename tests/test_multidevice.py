@@ -1,6 +1,7 @@
-"""Multi-device tests in subprocesses (8 forced host devices): pipeline
+"""Multi-device tests in subprocesses (forced host devices): pipeline
 parallelism, sharded train step with collectives, distributed join on a
 mesh. Subprocesses keep the main test session at 1 device."""
+import json
 import subprocess
 import sys
 import textwrap
@@ -8,11 +9,11 @@ import textwrap
 import pytest
 
 
-def _run(code: str, timeout: int = 900) -> str:
+def _run(code: str, timeout: int = 900, devices: int = 8) -> str:
     prelude = (
         "import os\n"
         "os.environ['XLA_FLAGS'] = "
-        "'--xla_force_host_platform_device_count=8'\n"
+        f"'--xla_force_host_platform_device_count={devices}'\n"
         "import sys\n"
         "sys.path.insert(0, 'src')\n"
     )
@@ -121,6 +122,53 @@ def test_distributed_join_on_mesh_matches_truth():
         print('DISTJOIN-OK', r, info['supersteps'])
     """)
     assert "DISTJOIN-OK" in out
+
+
+def test_distributed_join_chunked_dispatch_matches_one_chip_join():
+    # verify_batch=2 over 4 shards: at most 8 edges per dispatch, so a
+    # window's edges span several chunks
+    out = _run("""
+        import jax, numpy as np, tempfile, os
+        from repro.core import JoinConfig, build_bucket_graph
+        from repro.core.distributed import DistributedJoin
+        from repro.core.index import DiskJoinIndex
+        from repro.core.types import merge_config
+        from repro.data import clustered_vectors, epsilon_for_avg_neighbors
+        from repro.store.vector_store import FlatVectorStore
+
+        x = clustered_vectors(3000, 32, seed=4)
+        eps = epsilon_for_avg_neighbors(x, 10)
+        d = tempfile.mkdtemp()
+        store = FlatVectorStore.from_array(os.path.join(d, 'x.bin'), x)
+        cfg = JoinConfig(epsilon=eps, pad_align=64, num_buckets=16,
+                         memory_budget_bytes=x.nbytes // 4,
+                         compute_mode='device', verify_batch=2)
+        with DiskJoinIndex.build(store, cfg, os.path.join(d, 'i')) as index:
+            one = index.self_join()
+            flat = merge_config(index.build_config, index.query_defaults)
+            graph = build_bucket_graph(index.meta, flat)
+            mesh = jax.make_mesh((4,), ('data',))
+            pairs, info = DistributedJoin(index.store, index.meta, flat,
+                                          mesh=mesh).run(graph)
+        assert one.pairs.shape[0] > 0
+        assert np.array_equal(pairs, one.pairs)
+        assert np.array_equal(info['dists'], one.distances)
+        print('CHUNKED-OK', pairs.shape[0], info['supersteps'])
+    """, devices=4)
+    assert "CHUNKED-OK" in out
+
+
+def test_chip_smoke_four_chip_rehearsal():
+    # the CPU rehearsal of `chip_smoke.py --chips 4`: DistributedJoin on a
+    # 4-device data mesh against the one-chip join, and float64 truth
+    out = _run("""
+        sys.path.insert(0, '.')
+        import chip_smoke
+        sys.exit(chip_smoke.main(['--rehearse', '--chips', '4']))
+    """, devices=4)
+    last = json.loads(out.strip().splitlines()[-1])
+    assert last["ok"] is True and last["device"]["count"] == 4
+    assert "distributed vs one-chip" in out
 
 
 def test_fsdp_param_sharding_shards_embedding():
