@@ -29,7 +29,6 @@ from benchmarks import (common, fig7_baselines, fig8_recall, fig9_memory,
                         fig20_striping, fig21_online, fig22_scheduler,
                         fig23_device_pipeline, fig24_planner,
                         fig25_resilience, fig26_live, fig27_replication,
-                        kernel_roofline,
                         obs_trace, randomness)
 
 MODULES = [
@@ -55,7 +54,6 @@ MODULES = [
     ("fig27_replication", fig27_replication),
     ("obs_trace", obs_trace),
     ("randomness", randomness),
-    ("kernel_roofline", kernel_roofline),
 ]
 
 

@@ -37,6 +37,12 @@ would differ from the host engine's in the last bit.) Pair order
 parity: the compaction scatter walks the mask in row-major flat order,
 exactly ``np.nonzero``'s order.
 
+Device stages carry ``jax.named_scope`` names, which the profiler keeps
+on each device op: ``verify.stack`` (operand stack), ``verify.kernel``
+(the distance kernel) and ``compact.count`` / ``compact.search`` /
+``compact.gather`` (``compact_pairs``). They are trace-time metadata and
+cost nothing at run time.
+
 The compaction capacity (pairs per edge) adapts: a batch whose densest
 edge overflows the current capacity is re-compacted from its still-
 resident d2/mask at the next power of two (the kernel output was sized
@@ -54,7 +60,7 @@ import numpy as np
 from repro.compute.slab_pool import DeviceSlabPool
 from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.obs import get_tracer
+from repro.obs import compiles, get_tracer
 
 PAIR_CAP_INIT = 1024  # initial per-edge compaction capacity (pairs)
 
@@ -77,29 +83,32 @@ def compact_pairs(d2: jax.Array, mask: jax.Array, na: jax.Array,
     The caller takes the sqrt on the host (module docstring: parity).
     """
     E, M, N = d2.shape
-    rows = jax.lax.broadcasted_iota(jnp.int32, (M, N), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (M, N), 1)
-    live = ((rows[None] < na[:, None, None])
-            & (cols[None] < nb[:, None, None]))
-    tri = (~intra)[:, None, None] | (rows[None] < cols[None])
-    m = mask & live & tri
-    flat = m.reshape(E, M * N)
-    counts = jnp.sum(flat, axis=1, dtype=jnp.int32)
-    # prefix-sum + binary search: the j-th pair's flat position is the
-    # first index where the running count reaches j+1 — row-major flat
-    # order == np.nonzero extraction order (host parity). k_cap·log(M·N)
-    # searches vectorize where an XLA scatter would serialize per update
-    # and a full sort would pay M·N·log(M·N).
-    cs = jnp.cumsum(flat, axis=1, dtype=jnp.int32)
-    ks = jnp.arange(1, k_cap + 1, dtype=jnp.int32)
-    order = jax.vmap(lambda c: jnp.searchsorted(c, ks, side="left"))(cs)
-    valid = ks[None, :] <= counts[:, None]
-    order = jnp.minimum(order, M * N - 1)  # clamp past-count sentinels
-    out_r = jnp.where(valid, (order // N).astype(jnp.int32), 0)
-    out_c = jnp.where(valid, (order % N).astype(jnp.int32), 0)
-    out_d2 = jnp.where(
-        valid, jnp.take_along_axis(d2.reshape(E, M * N), order, axis=1),
-        0.0)
+    with jax.named_scope("compact.count"):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (M, N), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (M, N), 1)
+        live = ((rows[None] < na[:, None, None])
+                & (cols[None] < nb[:, None, None]))
+        tri = (~intra)[:, None, None] | (rows[None] < cols[None])
+        m = mask & live & tri
+        flat = m.reshape(E, M * N)
+        counts = jnp.sum(flat, axis=1, dtype=jnp.int32)
+        # prefix-sum + binary search: the j-th pair's flat position is the
+        # first index where the running count reaches j+1 — row-major flat
+        # order == np.nonzero extraction order (host parity). k_cap·log(M·N)
+        # searches vectorize where an XLA scatter would serialize per
+        # update and a full sort would pay M·N·log(M·N).
+        cs = jnp.cumsum(flat, axis=1, dtype=jnp.int32)
+    with jax.named_scope("compact.search"):
+        ks = jnp.arange(1, k_cap + 1, dtype=jnp.int32)
+        order = jax.vmap(lambda c: jnp.searchsorted(c, ks, side="left"))(cs)
+    with jax.named_scope("compact.gather"):
+        valid = ks[None, :] <= counts[:, None]
+        order = jnp.minimum(order, M * N - 1)  # clamp past-count sentinels
+        out_r = jnp.where(valid, (order // N).astype(jnp.int32), 0)
+        out_c = jnp.where(valid, (order % N).astype(jnp.int32), 0)
+        out_d2 = jnp.where(
+            valid, jnp.take_along_axis(d2.reshape(E, M * N), order, axis=1),
+            0.0)
     return counts, out_r, out_c, out_d2
 
 
@@ -116,9 +125,11 @@ def device_verify(na, nb, intra, *slabs, eps: float, k_cap: int,
     arrays (their H2D rides the dispatch).
     """
     B = len(slabs) // 2
-    u = jnp.stack(slabs[:B])
-    v = jnp.stack(slabs[B:])
-    d2, mask = kops.verify_pairs_batch(u, v, eps, use_pallas=use_pallas)
+    with jax.named_scope("verify.stack"):
+        u = jnp.stack(slabs[:B])
+        v = jnp.stack(slabs[B:])
+    with jax.named_scope("verify.kernel"):
+        d2, mask = kops.verify_pairs_batch(u, v, eps, use_pallas=use_pallas)
     counts, out_r, out_c, out_d = compact_pairs(d2, mask, na, nb, intra,
                                                 k_cap)
     # the stacked operands come back as outputs so the engine can harvest
@@ -136,12 +147,15 @@ def query_verify_compact(q_block: jax.Array, qidx: jax.Array, nq,
     padded rows repeat query 0 and are masked out by the row count.
     Returns compacted (counts (1,), q-rows, cols, d²) against the
     (capacity, dim) bucket slab."""
-    qs = jnp.take(q_block, qidx, axis=0)             # (Qp, d)
-    d2 = ref.pairwise_l2(qs, slab)[None]             # (1, Qp, cap)
+    with jax.named_scope("verify.stack"):
+        qs = jnp.take(q_block, qidx, axis=0)         # (Qp, d)
+    with jax.named_scope("verify.kernel"):
+        d2 = ref.pairwise_l2(qs, slab)[None]         # (1, Qp, cap)
+        mask = d2 <= eps2
     na = jnp.reshape(nq, (1,)).astype(jnp.int32)
     nb = jnp.full((1,), slab.shape[0], jnp.int32)
     intra = jnp.zeros((1,), bool)
-    return compact_pairs(d2, d2 <= eps2, na, nb, intra, k_cap)
+    return compact_pairs(d2, mask, na, nb, intra, k_cap)
 
 
 class _EngineBase:
@@ -160,6 +174,7 @@ class _EngineBase:
         self.attribute_mask = attribute_mask
         self.pstats = pstats
         self.tracer = tracer if tracer is not None else get_tracer()
+        compiles.install()
         self.xfer_gb_s = float(xfer_gb_s)
         self.dc = 0              # distance computations (live pairs)
         self.compute_s = 0.0     # engine wall time in stage/dispatch/extract
@@ -334,19 +349,20 @@ class DeviceVerifyEngine(_EngineBase):
         self.pool.evict(b)
 
     def enqueue(self, bu: int, bv: int, intra: bool) -> None:
-        ea = self.cache.checkout(bu)
-        eb = self.cache.checkout(bv)
-        try:
-            da = self.pool.operand(bu, ea[0])
-            db = self.pool.operand(bv, eb[0])
-            # id sidecars live in recyclable pool slots: copy the live
-            # rows so the pins can drop now (the pool operand is already
-            # an independent copy)
-            meta = (np.array(ea[1][:ea[2]]), ea[2],
-                    np.array(eb[1][:eb[2]]), eb[2], intra)
-        finally:
-            self.cache.release(ea)
-            self.cache.release(eb)
+        with self.tracer.span("verify.enqueue"):
+            ea = self.cache.checkout(bu)
+            eb = self.cache.checkout(bv)
+            try:
+                da = self.pool.operand(bu, ea[0])
+                db = self.pool.operand(bv, eb[0])
+                # id sidecars live in recyclable pool slots: copy the live
+                # rows so the pins can drop now (the pool operand is
+                # already an independent copy)
+                meta = (np.array(ea[1][:ea[2]]), ea[2],
+                        np.array(eb[1][:eb[2]]), eb[2], intra)
+            finally:
+                self.cache.release(ea)
+                self.cache.release(eb)
         self._batch.append((da, db, bu, bv, meta))
         if len(self._batch) >= self.verify_batch:
             self.flush()
@@ -433,6 +449,12 @@ class DeviceVerifyEngine(_EngineBase):
         # host time since dispatch ran concurrently with the kernel
         self._stat("d2h_overlap_s", max(0.0, t0 - t_dispatch))
         counts = np.asarray(out[0])
+        t_wait = time.perf_counter()
+        # the wait and extract spans share their intervals with the
+        # device_wait_s / extract_s accumulators (as io.wait does)
+        self._stat("device_wait_s", t_wait - t0)
+        self.tracer.complete("verify.wait", t0, t_wait - t0)
+        t_extract = t_wait
         top = int(counts.max()) if counts.size else 0
         if top > k_cap:
             # capacity overflow: the kernel output was sized too small,
@@ -440,10 +462,12 @@ class DeviceVerifyEngine(_EngineBase):
             k_cap = min(next_pow2(top), self.cap * self.cap)
             self.pair_cap = max(self.pair_cap, k_cap)
             self._stat("device_compact_overflows", 1)
-            self.tracer.instant("verify.overflow", top=top, k_cap=k_cap)
             out = device_verify(na, nb, intra, *slabs, eps=self.eps,
                                 k_cap=k_cap, use_pallas=self.use_pallas)
             counts = np.asarray(out[0])
+            t_extract = time.perf_counter()
+            self.tracer.complete("verify.recompact", t_wait,
+                                 t_extract - t_wait, top=top, k_cap=k_cap)
         # the queue is idle now: slice first-touch lanes out of the
         # stacked operands into the pool (device-resident for later
         # batches of this residency)
@@ -471,7 +495,10 @@ class DeviceVerifyEngine(_EngineBase):
             self.pairs_out.append(np.stack([pa, pb], axis=1)
                                   .astype(np.int64))
             self.dists_out.append(d.astype(np.float32))
-        self.compute_s += time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self._stat("extract_s", t1 - t_extract)
+        self.tracer.complete("verify.extract", t_extract, t1 - t_extract)
+        self.compute_s += t1 - t0
         span.__exit__(None, None, None)
 
     def finish(self) -> None:

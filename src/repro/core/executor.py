@@ -256,8 +256,9 @@ class JoinExecutor:
             ai += 1
             if not is_hit:
                 if victim is not None:
-                    cache.evict(victim)
-                    engine.evict(victim)
+                    with tracer.span("cache.evict", bucket=victim):
+                        cache.evict(victim)
+                        engine.evict(victim)
                 if not cache.load_issued:
                     # prefetcher is behind AND may be blocked on the pool:
                     # flush pending pins so a slab frees up (liveness)

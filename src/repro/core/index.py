@@ -62,7 +62,8 @@ from repro.core.types import (BUILD_TIME_FIELDS, QUERY_TIME_FIELDS,
 from repro.ft.atomic import AsyncCommitter, atomic_write_json
 from repro.io import BufferPool, PipelineStats
 from repro.io.retry import read_with_retry
-from repro.obs import MetricsRegistry, enable_tracing, get_tracer
+from repro.obs import (MetricsRegistry, compile_counts, enable_tracing,
+                       get_tracer)
 from repro.obs.live import LiveObserver, default_serving_slos
 from repro.plan import (SKETCH_FILE, CardinalityEstimator, CostModel,
                         Planner)
@@ -104,6 +105,8 @@ class DiskJoinIndex:
                                        lambda: self.store.stats.snapshot())
         # span drops must be visible without holding the tracer object
         self.metrics.register_provider("tracer", self._tracer_section)
+        # process-wide jit.compiles / jit.cache_hits (repro.obs.compiles)
+        self.metrics.register_provider("jit", compile_counts)
         self.bucket_capacity = resolve_bucket_capacity(build_config,
                                                        meta.sizes)
         self._pool: BufferPool | None = None

@@ -76,6 +76,8 @@ class PipelineStats:
     device_batches: int = 0          # double-buffered kernel dispatches
     device_compact_overflows: int = 0  # batches re-compacted at larger cap
     d2h_overlap_s: float = 0.0       # host work overlapped with the kernel
+    device_wait_s: float = 0.0       # collect blocked on the device
+    extract_s: float = 0.0           # collect's D2H and pair extraction
     device_loads: list = dataclasses.field(default_factory=list)
     device_depth_max: list = dataclasses.field(default_factory=list)
 

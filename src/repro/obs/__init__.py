@@ -18,6 +18,7 @@ from repro.obs.tracer import (
     get_tracer,
     trace_session,
 )
+from repro.obs.compiles import compile_counts
 from repro.obs.export import (
     TraceAnalysis,
     export_chrome_trace,
@@ -50,6 +51,7 @@ __all__ = [
     "enable_tracing",
     "disable_tracing",
     "trace_session",
+    "compile_counts",
     "TraceAnalysis",
     "export_chrome_trace",
     "validate_chrome_trace",

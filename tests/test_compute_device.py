@@ -435,3 +435,105 @@ class TestQueryDevice:
             # the wave's query block crossed once; bucket slabs reused it
             assert snap["h2d_transfers"] > base["h2d_transfers"]
             assert snap["h2d_transfers_saved"] > base["h2d_transfers_saved"]
+
+
+# ---------------------------------------------------------------------------
+# verify engine spans and counters, compiles counted in the program
+# ---------------------------------------------------------------------------
+class _Slabs:
+    """Checkout/release surface of the executor's bucket caches over
+    fixed in-memory slabs."""
+
+    def __init__(self, slabs):
+        self.slabs = slabs
+
+    def checkout(self, b):
+        vecs = self.slabs[b]
+        return (vecs, np.arange(vecs.shape[0]) + 1000 * b, vecs.shape[0],
+                None)
+
+    def release(self, entry):
+        pass
+
+
+class TestVerifySpans:
+    def _traced_engine_run(self, pair_cap):
+        from repro.compute import DeviceVerifyEngine
+        from repro.io import PipelineStats
+        from repro.obs import trace_session
+
+        rng = np.random.default_rng(5)
+        cap, dim = 32, 8
+        slabs = {b: (rng.normal(size=(cap, dim)) * 0.3).astype(np.float32)
+                 for b in range(4)}
+        stats = PipelineStats()
+        with trace_session() as tr:
+            eng = DeviceVerifyEngine(_Slabs(slabs), epsilon=0.6,
+                                     capacity_rows=cap, dim=dim,
+                                     verify_batch=2, pstats=stats,
+                                     tracer=tr, pair_cap=pair_cap)
+            for bu, bv in [(0, 0), (0, 1), (1, 2), (2, 3), (3, 3), (1, 3)]:
+                eng.enqueue(bu, bv, bu == bv)
+            eng.finish()
+        assert sum(p.shape[0] for p in eng.results()[0]) > 0
+        return [e for e in tr.events() if e["ph"] == "X"], stats
+
+    @pytest.mark.parametrize("pair_cap", [None, 1])
+    def test_collect_stages_nest_in_collect(self, pair_cap):
+        spans, stats = self._traced_engine_run(pair_cap)
+        collects = [e for e in spans if e["name"] == "verify.collect"]
+        assert len(collects) == stats.device_batches == 3
+        stages = {"verify.wait", "verify.extract"}
+        if pair_cap == 1:
+            stages.add("verify.recompact")
+            assert stats.device_compact_overflows >= 1
+        names = {e["name"] for e in spans}
+        assert stages <= names
+        for e in spans:
+            if e["name"] in stages | {"verify.recompact"}:
+                assert any(c["tid"] == e["tid"] and c["ts"] <= e["ts"]
+                           and e["ts"] + e["dur"] <= c["ts"] + c["dur"]
+                           for c in collects), e
+        assert {"verify.enqueue"} <= names
+        recompact = [e for e in spans if e["name"] == "verify.recompact"]
+        assert all(e["args"]["k_cap"] > 1 for e in recompact)
+
+    def test_counters_share_the_spans_intervals(self):
+        spans, stats = self._traced_engine_run(None)
+        total = {n: sum(e["dur"] for e in spans if e["name"] == n) / 1e6
+                 for n in ("verify.collect", "verify.wait",
+                           "verify.extract")}
+        assert stats.device_wait_s > 0 and stats.extract_s > 0
+        assert stats.device_wait_s + stats.extract_s <= \
+            total["verify.collect"]
+        assert stats.device_wait_s == pytest.approx(total["verify.wait"],
+                                                    rel=1e-6)
+        assert stats.extract_s == pytest.approx(total["verify.extract"],
+                                                rel=1e-6)
+
+    def test_fresh_shape_is_one_counted_compile(self):
+        import jax
+
+        from repro.compute.engine import device_verify
+        from repro.obs import compile_counts, trace_session
+
+        slab = np.zeros((16, 8), np.float32)
+        lanes = np.zeros(1, np.int32)
+        before = compile_counts()
+        with trace_session() as tr:
+            # k_cap 24 is a static value no other test uses: a new program
+            jax.block_until_ready(device_verify(
+                lanes, lanes, np.zeros(1, bool), slab, slab, eps=0.5,
+                k_cap=24))
+        after = compile_counts()
+        spans = [e for e in tr.events() if e["name"] == "jit.compile"]
+        assert len(spans) == 1
+        assert "device_verify" in spans[0]["args"]["fun_name"]
+        assert spans[0]["dur"] > 0
+        assert after["compiles"] - before["compiles"] == 1
+        with trace_session() as tr:              # cached: no compile
+            jax.block_until_ready(device_verify(
+                lanes, lanes, np.zeros(1, bool), slab, slab, eps=0.5,
+                k_cap=24))
+        assert compile_counts()["compiles"] == after["compiles"]
+        assert not [e for e in tr.events() if e["name"] == "jit.compile"]

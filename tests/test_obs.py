@@ -556,7 +556,8 @@ class TestEndToEnd:
         svc.query(x[1])
         snap = index.metrics_snapshot()
         assert {"counters", "gauges", "histograms", "pipeline",
-                "io"} <= set(snap)
+                "io", "jit"} <= set(snap)
+        assert {"compiles", "cache_hits"} <= set(snap["jit"])
         assert snap["service"]["requests"] == 2
         assert snap["service"]["latency_p95_ms"] > 0
         svc.close()

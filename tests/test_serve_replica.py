@@ -588,8 +588,10 @@ class TestWebhookSink:
             assert _Hook.received == [{"slo": "latency",
                                        "state": "firing",
                                        "fast_burn": 9.0}]
-            assert sink.snapshot()["delivered"] == 1
+            # the server records the payload before the sink reads the
+            # response and counts it: flush the sink first
             sink.close()
+            assert sink.snapshot()["delivered"] == 1
         finally:
             srv.shutdown()
 
