@@ -11,10 +11,12 @@ slab-pool lifecycle.
 """
 from repro.compute.engine import (PAIR_CAP_INIT, DeviceVerifyEngine,
                                   HostVerifyEngine, RoutedVerifyEngine,
-                                  compact_pairs, make_verify_engine,
-                                  next_pow2, query_verify_compact)
+                                  compact_batch, compact_pairs,
+                                  make_verify_engine, next_pow2,
+                                  query_verify_compact)
 from repro.compute.slab_pool import DeviceSlabPool
 
 __all__ = ["DeviceSlabPool", "DeviceVerifyEngine", "HostVerifyEngine",
-           "PAIR_CAP_INIT", "RoutedVerifyEngine", "compact_pairs",
-           "make_verify_engine", "next_pow2", "query_verify_compact"]
+           "PAIR_CAP_INIT", "RoutedVerifyEngine", "compact_batch",
+           "compact_pairs", "make_verify_engine", "next_pow2",
+           "query_verify_compact"]

@@ -40,13 +40,17 @@ exactly ``np.nonzero``'s order.
 Device stages carry ``jax.named_scope`` names, which the profiler keeps
 on each device op: ``verify.stack`` (operand stack), ``verify.kernel``
 (the distance kernel) and ``compact.count`` / ``compact.search`` /
-``compact.gather`` (``compact_pairs``). They are trace-time metadata and
-cost nothing at run time.
+``compact.gather`` (``compact_batch`` / ``compact_pairs``). They are
+trace-time metadata and cost nothing at run time.
 
-The compaction capacity (pairs per edge) adapts: a batch whose densest
-edge overflows the current capacity is re-compacted from its still-
-resident d2/mask at the next power of two (the kernel output was sized
-too small, not wrong), and the larger capacity sticks for later batches.
+The join's compaction capacity is pooled over the batch
+(``compact_batch``): one capacity bounds the pairs of all lanes, so the
+search covers the batch's real total and not every lane's worst case.
+It adapts: a batch whose total overflows the current capacity is
+re-compacted from its still-resident d2/mask at the next power of two
+(the kernel output was sized too small, not wrong), and the larger
+capacity sticks for later batches. The query and distributed paths keep
+one capacity per lane (``compact_pairs``).
 """
 from __future__ import annotations
 
@@ -69,10 +73,61 @@ def next_pow2(x: int) -> int:
     return 1 << max(0, int(x) - 1).bit_length()
 
 
+def _kept_flags(mask, na, nb, intra):
+    """The pairs a compaction keeps, as (E, M, N) flags: the kernel's
+    threshold mask within each lane's live rows (``na``/``nb``, 0 kills a
+    padded lane), strictly upper for ``intra`` lanes."""
+    E, M, N = mask.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (M, N), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (M, N), 1)
+    live = ((rows[None] < na[:, None, None])
+            & (cols[None] < nb[:, None, None]))
+    tri = (~intra)[:, None, None] | (rows[None] < cols[None])
+    return mask & live & tri
+
+
+SEARCH_BLOCK = 128  # entries a rank gathers per level of _first_at_least
+
+
+def _first_at_least(a, rows, ks):
+    """For each i, the first column c with ``a[rows[i], c] >= ks[i]``, and
+    the entry before it (``a[rows[i], c - 1]``, 0 at c = 0), in an (R, n)
+    int32 array of non-negative rows that never decrease. Where no entry
+    reaches ``ks[i]``, c is n or more.
+
+    A search over blocks of ``SEARCH_BLOCK``: each level gathers one
+    contiguous block per rank and counts its entries below the rank, so
+    a rank costs about log_128(n) gathers of a block where a binary
+    search costs log_2(n) gathers of one entry. One row of up to 16
+    blocks is compared whole, with no gather.
+    """
+    R, n = a.shape
+    if n <= SEARCH_BLOCK or (R == 1 and n <= 16 * SEARCH_BLOCK):
+        top = a if R == 1 else a[rows]
+        below = top < ks[:, None]
+        return (jnp.sum(below, axis=1, dtype=jnp.int32),
+                jnp.max(jnp.where(below, top, 0), axis=1))
+    pad = -n % SEARCH_BLOCK
+    if pad:  # repeat each row's last entry: rows still never decrease
+        a = jnp.concatenate(
+            [a, jnp.broadcast_to(a[:, -1:], (R, pad))], axis=1)
+    nblk = a.shape[1] // SEARCH_BLOCK
+    blocks = a.reshape(R, nblk, SEARCH_BLOCK)
+    # searching the blocks' last entries finds the rank's block, and the
+    # entry before that block
+    b, before = _first_at_least(blocks[:, :, -1], rows, ks)
+    b = jnp.minimum(b, nblk - 1)
+    blk = blocks[rows, b]
+    below = blk < ks[:, None]
+    return (b * SEARCH_BLOCK + jnp.sum(below, axis=1, dtype=jnp.int32),
+            jnp.maximum(before, jnp.max(jnp.where(below, blk, 0), axis=1)))
+
+
 @functools.partial(jax.jit, static_argnames=("k_cap",))
 def compact_pairs(d2: jax.Array, mask: jax.Array, na: jax.Array,
                   nb: jax.Array, intra: jax.Array, k_cap: int):
-    """On-device pair compaction: mask → prefix-sum → gather.
+    """On-device pair compaction, one capacity per lane: mask → prefix-sum
+    → gather.
 
     d2/mask: (E, M, N); na/nb: (E,) int32 live-row counts (0 kills a
     padded batch lane); intra: (E,) bool — keep strictly-upper pairs only
@@ -84,13 +139,7 @@ def compact_pairs(d2: jax.Array, mask: jax.Array, na: jax.Array,
     """
     E, M, N = d2.shape
     with jax.named_scope("compact.count"):
-        rows = jax.lax.broadcasted_iota(jnp.int32, (M, N), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (M, N), 1)
-        live = ((rows[None] < na[:, None, None])
-                & (cols[None] < nb[:, None, None]))
-        tri = (~intra)[:, None, None] | (rows[None] < cols[None])
-        m = mask & live & tri
-        flat = m.reshape(E, M * N)
+        flat = _kept_flags(mask, na, nb, intra).reshape(E, M * N)
         counts = jnp.sum(flat, axis=1, dtype=jnp.int32)
         # prefix-sum + binary search: the j-th pair's flat position is the
         # first index where the running count reaches j+1 — row-major flat
@@ -112,6 +161,62 @@ def compact_pairs(d2: jax.Array, mask: jax.Array, na: jax.Array,
     return counts, out_r, out_c, out_d2
 
 
+@functools.partial(jax.jit, static_argnames=("k_cap",))
+def compact_batch(d2: jax.Array, mask: jax.Array, na: jax.Array,
+                  nb: jax.Array, intra: jax.Array, k_cap: int):
+    """On-device pair compaction, one capacity pooled over the batch.
+
+    Same inputs and kept pairs as ``compact_pairs``, but ``k_cap`` bounds
+    the batch's total: ranks 1..k_cap are searched once in the running
+    count of the batch's E·M·N flags, first for the slab row that holds
+    the rank, then for its block of ``SEARCH_BLOCK`` columns in that row,
+    then for its column in that block. Returns (counts (E,)
+    int32, rows (k_cap,) int32, cols (k_cap,) int32, d2 (k_cap,) f32),
+    lane-major: lane e's pairs occupy ``[off[e], off[e] + counts[e])``
+    with ``off`` the exclusive cumsum of ``counts``, each lane in
+    ``np.nonzero`` order. Counts stay exact past ``k_cap``; only the
+    first ``k_cap`` pairs of the batch land (the caller re-compacts at
+    the total).
+    """
+    E, M, N = d2.shape
+    if E * M * N >= 2 ** 31:
+        raise ValueError(f"batch of {E}x{M}x{N} flags overflows the int32 "
+                         "running count")
+    R = E * M
+    with jax.named_scope("compact.count"):
+        kept = _kept_flags(mask, na, nb, intra).reshape(R, N)
+        pad = -N % SEARCH_BLOCK
+        if pad:
+            kept = jnp.pad(kept, ((0, 0), (0, pad)))
+        blocks = kept.reshape(R, -1, SEARCH_BLOCK)
+        # each slab row's running count at its blocks' ends, and the
+        # batch's running count at the rows' ends; the full running count
+        # is never formed, only the block a rank lands in
+        blk_end = jnp.cumsum(jnp.sum(blocks, axis=2, dtype=jnp.int32),
+                             axis=1, dtype=jnp.int32)
+        row_n = blk_end[:, -1]
+        counts = jnp.sum(row_n.reshape(E, M), axis=1, dtype=jnp.int32)
+        row_end = jnp.cumsum(row_n, dtype=jnp.int32)
+    with jax.named_scope("compact.search"):
+        ks = jnp.arange(1, k_cap + 1, dtype=jnp.int32)
+        row, row_start = _first_at_least(row_end[None], jnp.zeros_like(ks),
+                                         ks)
+        row = jnp.minimum(row, R - 1)
+        in_row = ks - row_start
+        b, blk_start = _first_at_least(blk_end, row, in_row)
+        b = jnp.minimum(b, blocks.shape[1] - 1)
+        run = jnp.cumsum(blocks[row, b], axis=1, dtype=jnp.int32)
+        col = b * SEARCH_BLOCK + jnp.sum(
+            run < (in_row - blk_start)[:, None], axis=1, dtype=jnp.int32)
+        col = jnp.minimum(col, N - 1)
+    with jax.named_scope("compact.gather"):
+        valid = ks <= row_end[-1]
+        out_r = jnp.where(valid, row % M, 0)
+        out_c = jnp.where(valid, col, 0)
+        out_d2 = jnp.where(valid, d2.reshape(R, N)[row, col], 0.0)
+    return counts, out_r, out_c, out_d2
+
+
 @functools.partial(jax.jit, static_argnames=("eps", "k_cap", "use_pallas"))
 def device_verify(na, nb, intra, *slabs, eps: float, k_cap: int,
                   use_pallas: bool = False):
@@ -122,7 +227,8 @@ def device_verify(na, nb, intra, *slabs, eps: float, k_cap: int,
     so the whole batch is ONE asynchronous dispatch — an eager
     ``jnp.stack`` would synchronize with the in-flight previous batch
     and stall the double buffer. First-touch slabs may arrive as numpy
-    arrays (their H2D rides the dispatch).
+    arrays (their H2D rides the dispatch). ``k_cap`` is the batch's pair
+    capacity (``compact_batch``: lane-major flat outputs).
     """
     B = len(slabs) // 2
     with jax.named_scope("verify.stack"):
@@ -130,7 +236,7 @@ def device_verify(na, nb, intra, *slabs, eps: float, k_cap: int,
         v = jnp.stack(slabs[B:])
     with jax.named_scope("verify.kernel"):
         d2, mask = kops.verify_pairs_batch(u, v, eps, use_pallas=use_pallas)
-    counts, out_r, out_c, out_d = compact_pairs(d2, mask, na, nb, intra,
+    counts, out_r, out_c, out_d = compact_batch(d2, mask, na, nb, intra,
                                                 k_cap)
     # the stacked operands come back as outputs so the engine can harvest
     # first-touch lanes into the device slab pool once the batch lands
@@ -332,12 +438,20 @@ class DeviceVerifyEngine(_EngineBase):
                                    tracer=self.tracer)
         self._batch: list[tuple] = []
         self._inflight: tuple | None = None
-        # start the compaction capacity at ~8 pairs per slab row: dense
+        # the batch's pair capacity starts at ~8 pairs per slab row: dense
         # enough that overflow re-compaction (and its recompile) is rare,
-        # small enough that the compacted D2H stays ≪ the full mask
-        cap2 = self.cap * self.cap
-        self.pair_cap = min(
-            next_pow2(pair_cap or max(PAIR_CAP_INIT, 8 * self.cap)), cap2)
+        # small enough that the compacted D2H stays ≪ the full mask. A
+        # planned per-lane ``pair_cap`` floors it at pair_cap × lanes.
+        self.lane_cap = next_pow2(pair_cap) if pair_cap else 0
+        self.pair_cap = 0 if pair_cap else min(
+            next_pow2(max(PAIR_CAP_INIT, 8 * self.cap)), self.cap * self.cap)
+
+    def _batch_cap(self, lanes: int, total: int = 0) -> int:
+        """Pair capacity of a ``lanes``-lane batch: the sticky batch
+        capacity, the planned per-lane floor and ``total`` pairs, as a
+        power of two, and never more than the batch's flags."""
+        k = max(self.pair_cap, self.lane_cap * lanes, next_pow2(total))
+        return min(k, lanes * self.cap * self.cap)
 
     @property
     def pending(self) -> bool:
@@ -424,7 +538,7 @@ class DeviceVerifyEngine(_EngineBase):
             if bv not in staged and self.pool.needs_harvest(bv):
                 harvest.append((bv, 1, i))
                 staged.add(bv)
-        k_cap = self.pair_cap
+        k_cap = self._batch_cap(B)
         out = device_verify(na, nb, intra, *slabs, eps=self.eps,
                             k_cap=k_cap, use_pallas=self.use_pallas)
         self._batch.clear()
@@ -455,19 +569,23 @@ class DeviceVerifyEngine(_EngineBase):
         self._stat("device_wait_s", t_wait - t0)
         self.tracer.complete("verify.wait", t0, t_wait - t0)
         t_extract = t_wait
-        top = int(counts.max()) if counts.size else 0
-        if top > k_cap:
+        total = int(counts.sum())
+        self._stat("device_compact_slots", k_cap)
+        if self.pstats is not None:
+            self.pstats.observe_max("device_batch_pairs_max", total)
+        if total > k_cap:
             # capacity overflow: the kernel output was sized too small,
             # not wrong — re-dispatch at the next pow2, which sticks
-            k_cap = min(next_pow2(top), self.cap * self.cap)
+            k_cap = self._batch_cap(na.size, total)
             self.pair_cap = max(self.pair_cap, k_cap)
             self._stat("device_compact_overflows", 1)
+            self._stat("device_compact_slots", k_cap)
             out = device_verify(na, nb, intra, *slabs, eps=self.eps,
                                 k_cap=k_cap, use_pallas=self.use_pallas)
             counts = np.asarray(out[0])
             t_extract = time.perf_counter()
             self.tracer.complete("verify.recompact", t_wait,
-                                 t_extract - t_wait, top=top, k_cap=k_cap)
+                                 t_extract - t_wait, top=total, k_cap=k_cap)
         # the queue is idle now: slice first-touch lanes out of the
         # stacked operands into the pool (device-resident for later
         # batches of this residency)
@@ -475,18 +593,21 @@ class DeviceVerifyEngine(_EngineBase):
             self.pool.harvest(b, out[4 + side][lane])
         rows = np.asarray(out[1])
         cols = np.asarray(out[2])
-        dists = np.sqrt(np.asarray(out[3]))
-        fetched = counts.nbytes + rows.nbytes + cols.nbytes + dists.nbytes
+        d2 = np.asarray(out[3])
+        fetched = counts.nbytes + rows.nbytes + cols.nbytes + d2.nbytes
         self._stat("d2h_bytes", fetched)
         self._charge_link(fetched)
+        dists = np.sqrt(d2[:total])
+        ends = np.cumsum(counts)
         attr = self.attribute_mask
         for i, (ids_a, ids_b) in enumerate(metas):
             k = int(counts[i])
             if k == 0:
                 continue
-            pa = ids_a[rows[i, :k]]
-            pb = ids_b[cols[i, :k]]
-            d = dists[i, :k]
+            lane = slice(int(ends[i]) - k, int(ends[i]))
+            pa = ids_a[rows[lane]]
+            pb = ids_b[cols[lane]]
+            d = dists[lane]
             if attr is not None:
                 keep = attr[pa] & attr[pb]
                 pa, pb, d = pa[keep], pb[keep], d[keep]

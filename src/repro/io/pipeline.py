@@ -75,6 +75,8 @@ class PipelineStats:
     device_slab_hits: int = 0        # lookups hitting the device slab pool
     device_batches: int = 0          # double-buffered kernel dispatches
     device_compact_overflows: int = 0  # batches re-compacted at larger cap
+    device_compact_slots: int = 0    # pair capacity summed over dispatches
+    device_batch_pairs_max: int = 0  # most pairs one device batch held
     d2h_overlap_s: float = 0.0       # host work overlapped with the kernel
     device_wait_s: float = 0.0       # collect blocked on the device
     extract_s: float = 0.0           # collect's D2H and pair extraction
@@ -89,8 +91,11 @@ class PipelineStats:
             setattr(self, field, getattr(self, field) + amount)
 
     def observe_depth(self, depth: int) -> None:
+        self.observe_max("max_queue_depth", depth)
+
+    def observe_max(self, field: str, value) -> None:
         with self._lock:
-            self.max_queue_depth = max(self.max_queue_depth, depth)
+            setattr(self, field, max(getattr(self, field), value))
 
     # -- per-device telemetry -------------------------------------------------
     def init_devices(self, num_devices: int) -> None:
@@ -119,7 +124,7 @@ class PipelineStats:
     GAUGE_FIELDS = frozenset({
         "pool_slabs", "lookahead", "num_devices", "max_queue_depth",
         "max_slabs_in_use", "blocked_acquires", "device_depth_max",
-        "planned_pair_cap",
+        "planned_pair_cap", "device_batch_pairs_max",
     })
 
     def snapshot(self) -> dict:

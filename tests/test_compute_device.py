@@ -276,6 +276,79 @@ class TestCompaction:
         assert np.array_equal(r[0], rows[:k_cap])
         assert np.array_equal(c[0], cols[:k_cap])
 
+    @pytest.mark.parametrize("R,n", [
+        (1, 1), (1, 128), (1, 129), (1, 70000), (5, 300), (3, 2048)])
+    def test_block_search_matches_searchsorted(self, R, n):
+        import jax.numpy as jnp
+
+        from repro.compute.engine import _first_at_least
+
+        rng = np.random.default_rng(n)
+        a = np.cumsum(rng.random((R, n)) < 0.3, axis=1).astype(np.int32)
+        rows = rng.integers(0, R, 500).astype(np.int32)
+        ks = rng.integers(0, a.max() + 3, 500).astype(np.int32)
+        ks[:5] = a.max() + 1
+        got, before = [np.asarray(o) for o in _first_at_least(
+            jnp.asarray(a), jnp.asarray(rows), jnp.asarray(ks))]
+        want = np.array([np.searchsorted(a[r], k, side="left")
+                         for r, k in zip(rows, ks)])
+        found = want < n
+        assert found.any() and not found.all()
+        assert np.array_equal(got[found], want[found])
+        assert (got[~found] >= n).all()  # no entry reaches the rank
+        prev = np.where(want > 0, a[rows, np.maximum(want - 1, 0)], 0)
+        assert np.array_equal(before[found], prev[found])
+
+    @staticmethod
+    def _lanes_nonzero(d2, mask, na, nb, intra):
+        """Per-lane ``np.nonzero`` pairs, concatenated lane-major."""
+        rows, cols, vals, counts = [], [], [], []
+        for e in range(d2.shape[0]):
+            m = mask[e][:na[e], :nb[e]]
+            if intra[e]:
+                m = np.triu(m, k=1)
+            r, c = np.nonzero(m)
+            rows.append(r)
+            cols.append(c)
+            vals.append(d2[e][r, c])
+            counts.append(r.size)
+        return (np.array(counts), np.concatenate(rows),
+                np.concatenate(cols), np.concatenate(vals))
+
+    @pytest.mark.parametrize("E,M,N,k_cap,overflow", [
+        (1, 24, 17, 256, False), (3, 24, 17, 512, False),
+        (8, 24, 17, 1024, False), (8, 24, 17, 128, True),
+        (4, 136, 260, 32768, False)])
+    def test_batch_matches_lane_major_nonzero(self, E, M, N, k_cap,
+                                              overflow):
+        import jax.numpy as jnp
+
+        from repro.compute import compact_batch
+
+        d2, mask = self._mask_case(seed=E, E=E, M=M, N=N)
+        lane = np.arange(E)
+        na = np.where(lane % 4 == 2, 0, M - lane).astype(np.int32)
+        nb = np.full(E, N, np.int32)
+        intra = lane % 3 == 1
+        counts, r, c, d = [np.asarray(o) for o in compact_batch(
+            jnp.asarray(d2), jnp.asarray(mask), jnp.asarray(na),
+            jnp.asarray(nb), jnp.asarray(intra), k_cap)]
+        want_counts, rows, cols, vals = self._lanes_nonzero(
+            d2, mask, na, nb, intra)
+        assert r.shape == c.shape == d.shape == (k_cap,)
+        assert np.array_equal(counts, want_counts)  # exact past k_cap
+        total = int(want_counts.sum())
+        # the overflow case: the batch exceeds k_cap, no lane does
+        assert (total > k_cap) == overflow
+        assert want_counts.max() <= k_cap
+        k = min(total, k_cap)
+        assert np.array_equal(r[:k], rows[:k])
+        assert np.array_equal(c[:k], cols[:k])
+        np.testing.assert_array_equal(d[:k], vals[:k])
+        assert not (r[k:].any() or c[k:].any() or d[k:].any())
+        if E >= 3:
+            assert counts[2] == 0  # na = 0 kills the lane
+
     def test_executor_overflow_recovery(self, tmp_path):
         """A pair-dense workload whose first batches overflow the initial
         compaction capacity must still match host results exactly."""
@@ -301,6 +374,11 @@ class TestCompaction:
         assert rh.pairs.shape[0] > 1000
         assert np.array_equal(rh.pairs, rd.pairs)
         assert np.array_equal(rh.distances, rd.distances)
+        pipe = rd.io_stats["pipeline"]
+        assert pipe["device_compact_overflows"] >= 1
+        assert pipe["device_batch_pairs_max"] > 8
+        # every returned pair sat in a compaction slot
+        assert pipe["device_compact_slots"] >= rd.pairs.shape[0]
 
 
 # ---------------------------------------------------------------------------
@@ -510,6 +588,55 @@ class TestVerifySpans:
                                                     rel=1e-6)
         assert stats.extract_s == pytest.approx(total["verify.extract"],
                                                 rel=1e-6)
+
+    def test_batch_total_overflow_parity_and_counters(self, monkeypatch):
+        """Lanes that each fit the batch capacity but together overflow
+        it: the batch re-compacts at the total's power of two, results
+        stay byte-identical to the host engine, and the two compaction
+        counters read what they define."""
+        from repro.compute import DeviceVerifyEngine, HostVerifyEngine
+        from repro.compute import engine as eng
+        from repro.io import PipelineStats
+
+        rng = np.random.default_rng(8)
+        cap, dim = 16, 4
+        # start capacity 8 pairs a slab row: 128 a batch, as at cap 2048
+        monkeypatch.setattr(eng, "PAIR_CAP_INIT", 8)
+        slabs = {b: (rng.normal(size=(cap, dim)) * 0.5).astype(np.float32)
+                 for b in range(4)}
+        edges = [(0, 0), (0, 1), (1, 2), (2, 3), (3, 3), (1, 3), (0, 2)]
+        calls = []
+        real = eng.device_verify
+
+        def recorded(*args, **kw):
+            out = real(*args, **kw)
+            calls.append((kw["k_cap"], np.asarray(out[0])))
+            return out
+
+        monkeypatch.setattr(eng, "device_verify", recorded)
+        kw = dict(epsilon=1.0, capacity_rows=cap, dim=dim, verify_batch=4)
+        stats = PipelineStats()
+        dev = DeviceVerifyEngine(_Slabs(slabs), pstats=stats, **kw)
+        host = HostVerifyEngine(_Slabs(slabs), **kw)
+        for bu, bv in edges:
+            dev.enqueue(bu, bv, bu == bv)
+            host.enqueue(bu, bv, bu == bv)
+        dev.finish()
+        host.finish()
+        first_k = calls[0][0]
+        assert first_k == 128
+        overflowed = [cnt for k, cnt in calls
+                      if k == first_k and cnt.sum() > k]
+        assert overflowed and all(c.max() <= first_k for c in overflowed)
+        assert stats.device_compact_overflows >= 1
+        assert stats.device_compact_slots == sum(k for k, _ in calls)
+        assert stats.device_batch_pairs_max == max(
+            int(cnt.sum()) for _, cnt in calls)
+        assert stats.device_batch_pairs_max > first_k
+        hp, hd = host.results()
+        dp, dd = dev.results()
+        assert np.array_equal(np.concatenate(hp), np.concatenate(dp))
+        assert np.array_equal(np.concatenate(hd), np.concatenate(dd))
 
     def test_fresh_shape_is_one_counted_compile(self):
         import jax

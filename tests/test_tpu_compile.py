@@ -62,13 +62,14 @@ def test_fused_device_verify_compiles_with_pallas(one_chip, monkeypatch):
     # steer the kernel wrapper to the compiled (not interpreted) kernel:
     # the process's own backend is the CPU
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    lanes = 8
+    # the join's batch: 32 lanes, and the pooled pair capacity it runs at
+    lanes = 32
     count = _spec((lanes,), one_chip, jnp.int32)
     intra = _spec((lanes,), one_chip, jnp.bool_)
     slab = _spec((CAP, DIM), one_chip)
     compiled = device_verify.lower(
         count, count, intra, *([slab] * (2 * lanes)), eps=0.3,
-        k_cap=1024, use_pallas=True).compile()
+        k_cap=32768, use_pallas=True).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
