@@ -5,14 +5,17 @@ root and lives in files of its own under this directory:
 
 - ``configs/<config>.json``: the deployment (sizes, data, program settings,
   correctness limits), as the workload's ``config`` entry names it;
-- ``traffic/<traffic>.json``: the traffic mix, read by ``drivers.py``
-  according to its ``kind``;
+- ``traffic/<traffic>.json``: the traffic mix, whose ``kind`` names its
+  driver;
+- ``kinds/<kind>.py``: one driver per traffic kind, with a ``drive(run)``
+  that runs the cell once and returns a ``drivers.Outcome``; what the
+  kinds share is in ``drivers.py``;
 - ``metrics/<metric>.py``: one reader per per-layer metric, with a
   ``read(ctx)`` that returns the number or None when there is nothing to
   read.
 
-A new cell, configuration, mix or metric is therefore new files and new
-entries in ``BENCHMARK.json``; no file here needs an edit.
+A new cell, configuration, mix, kind or metric is therefore new files and
+new entries in ``BENCHMARK.json``; no file here needs an edit.
 """
 from __future__ import annotations
 
@@ -62,17 +65,30 @@ def _merge(base: dict, over: dict) -> dict:
     return out
 
 
+def _load_module(path: str, prefix: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str):
     """``metrics/<name>.py``'s ``read``; a name may hold dots."""
     path = os.path.join(HERE, "metrics", f"{name}.py")
     if not os.path.exists(path):
         raise CellError(f"reader of per-layer metric {name!r} not found: "
                         f"{path}")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "chipbench_metric_", name).read
+
+
+def load_driver(kind: str):
+    """``kinds/<kind>.py``'s ``drive``, for a traffic mix's ``kind``."""
+    path = os.path.join(HERE, "kinds", f"{kind}.py")
+    if not os.path.exists(path):
+        raise CellError(f"driver of traffic kind {kind!r} not found: "
+                        f"{path}")
+    return _load_module(path, "chipbench_kind_", kind).drive
 
 
 def load_benchmark(root: str = ROOT) -> dict:

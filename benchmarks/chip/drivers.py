@@ -1,16 +1,19 @@
-"""The kinds of traffic, each driven through the program's normal path:
-whole self-joins (``join``).
+"""What the traffic kinds (``kinds/<kind>.py``) share.
 
-Each driver makes the cell's data from the seed, builds the index with the
-configuration's settings, warms up every program shape its window will
-use (set-up), runs the window, reads the device's peak memory, frees the
-program's state, and only then compares what the window produced with the
-float64 reference. It returns an ``Outcome``; ``run.py`` prints it.
+A kind's ``drive(run)`` makes the cell's data from the seed, builds the
+index with the configuration's settings, warms up every program shape its
+window will use (set-up), runs the window, reads the device's peak memory,
+frees the program's state, and only then compares what the window produced
+with the float64 reference. It returns an ``Outcome``; ``run.py`` prints
+it. Here are the pieces they have in common: the run's context (``Run``),
+compile counting, the measured window, the data, the index build, the
+window of whole jobs and the check of join results.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import hashlib
 import os
 import resource
 import time
@@ -135,23 +138,6 @@ def _build(run: Run, x: np.ndarray, eps: float):
     return index, jc
 
 
-def _pow2_upto(n: int) -> list[int]:
-    from repro.compute import next_pow2
-    out, p = [], 1
-    while p <= next_pow2(n):
-        out.append(p)
-        p *= 2
-    return out
-
-
-def _k_caps(cap: int, doublings: int) -> list[int]:
-    """The device compaction capacity a join starts at, and the raises
-    an overflow reaches (each a program of its own)."""
-    from repro.compute import PAIR_CAP_INIT, next_pow2
-    base = min(next_pow2(max(PAIR_CAP_INIT, 8 * cap)), cap * cap)
-    return sorted({min(base << i, cap * cap) for i in range(doublings + 1)})
-
-
 def _memory_peak(devices) -> int:
     peaks = []
     for d in devices:
@@ -197,41 +183,35 @@ class _Window:
         return False
 
 
-# ---------------------------------------------------------------------------
-# whole self-joins
-# ---------------------------------------------------------------------------
-def _warm_join(run: Run, index, jc) -> None:
-    """Every program a self-join of this index runs: the bucket graph's
-    centre search, and the fused device verify at each power-of-two lane
-    count up to ``verify_batch`` and each compaction capacity, with the
-    slice that harvests a lane."""
-    import jax
-
-    from repro.compute.engine import device_verify
-    from repro.core import build_bucket_graph
-    build_bucket_graph(index.meta, jc)
-    cap, dim = index.bucket_capacity, index.dim
-    slab = np.zeros((cap, dim), np.float32)
-    for lanes in _pow2_upto(jc.verify_batch):
-        zero = np.zeros(lanes, np.int32)
-        intra = np.zeros(lanes, bool)
-        for k_cap in _k_caps(cap, run.cell.traffic["warm_k_cap_doublings"]):
-            out = device_verify(zero, zero, intra, *([slab] * (2 * lanes)),
-                                eps=float(jc.epsilon), k_cap=k_cap,
-                                use_pallas=jc.use_pallas)
-            jax.block_until_ready(out)
-            np.asarray(out[4][0])
+def _result_key(pairs: np.ndarray, dists: np.ndarray) -> str:
+    """sha256 of a join result in canonical form: pairs sorted by (low,
+    high), with their distances in the same order."""
+    p = np.asarray(pairs, np.int64).reshape(-1, 2)
+    order = np.lexsort((p[:, 1], p[:, 0]))
+    h = hashlib.sha256(p[order].tobytes())
+    h.update(np.asarray(dists, np.float32)[order].tobytes())
+    return h.hexdigest()
 
 
 def _check_join(run: Run, x, eps, results) -> dict:
+    """Every job's result judged against float64. Jobs with the same
+    result (by ``_result_key``) share one comparison, which is exact: the
+    numbers depend on nothing but the pairs and distances."""
     ref = reference.Reference(x, eps)
     rows = reference.sample_rows(x.shape[0],
                                  run.cell.traffic["check_rows"], run.seed)
     jt = reference.JoinTruth(ref, rows)
     limits = run.cell.config["limits"]
-    return reference.worst([
-        reference.judge(reference.compare_join(jt, p, d), limits)
-        for p, d in results])
+    numbers: dict = {}
+    keys = []
+    for p, d in results:
+        key = _result_key(p, d)
+        if key not in numbers:
+            numbers[key] = reference.compare_join(jt, p, d)
+        keys.append(key)
+    run.log(f"check: {len(results)} jobs, {len(numbers)} distinct results")
+    return reference.worst([reference.judge(numbers[k], limits)
+                            for k in keys])
 
 
 def _host_usage() -> np.ndarray:
@@ -254,52 +234,3 @@ def _join_window(run: Run, once) -> tuple[list, list, list]:
         usage.append(_host_usage() - u0)
         if time.perf_counter() - t_start + durations[-1] > run.seconds:
             return outs, durations, usage
-
-
-def drive_join(run: Run) -> Outcome:
-    import jax
-    t0 = time.perf_counter()
-    x, eps = _data(run)
-    run.log(f"data: {x.shape[0]} x {x.shape[1]} float32 in "
-            f"{time.perf_counter() - t0:.3f}s")
-    index, jc = _build(run, x, eps)
-    _warm_join(run, index, jc)
-    jax.effects_barrier()
-    setup_s = time.perf_counter() - t0
-    with _Window(run) as win:
-        results, durations, usage = _join_window(run, index.self_join)
-    peak = _memory_peak(run.devices[:run.cell.chips])
-    index.close()
-    last = results[-1]
-    pipe = last.io_stats.get("pipeline") or {}
-    counters = {"dim": index.dim, "joins": len(results),
-                "num_distance_computations": last.num_distance_computations,
-                "h2d_bytes": pipe.get("h2d_bytes", 0),
-                "device_batches": pipe.get("device_batches", 0),
-                "device_compact_overflows":
-                    pipe.get("device_compact_overflows", 0)}
-    for r, dt, u in zip(results, durations, usage):
-        p = r.io_stats.get("pipeline") or {}
-        t = r.timings
-        run.log(f"join: {dt:.3f}s pairs={r.pairs.shape[0]} "
-                f"distance_computations={r.num_distance_computations} "
-                f"device_batches={p.get('device_batches', 0)} "
-                f"compact_overflows={p.get('device_compact_overflows', 0)} "
-                f"bucket_loads={r.bucket_loads} "
-                f"orchestration_s={t['orchestration']:.3f} "
-                f"execute_s={t['execute']:.3f} "
-                f"engine_s={t['compute']:.3f} io_wait_s={t['io_wait']:.3f} "
-                f"host_user_s={u[0]:.3f} host_sys_s={u[1]:.3f} "
-                f"involuntary_switches={int(u[2])} "
-                f"major_faults={int(u[3])}")
-    check = _check_join(run, x, eps,
-                        [(r.pairs, r.distances) for r in results])
-    rows = x.shape[0] * len(results)
-    return Outcome(
-        setup_s=setup_s,
-        metrics={"join_vectors_per_s": rows / sum(durations)},
-        attempted=len(results), failed=0, check=check, counters=counters,
-        spans=win.events, trace=win.trace, memory_peak_bytes=peak)
-
-
-DRIVERS = {"join": drive_join}

@@ -3,8 +3,9 @@
     python3 benchmarks/chip/run.py --workload <name> --seed <n> \
         --seconds <s> --trace <0|1>
 
-The cell, its configuration, traffic mix and per-layer metric readers are
-found by name from ``BENCHMARK.json`` (``cells.py``). The run makes its
+The cell, its configuration, traffic mix, the driver of the mix's kind and
+the per-layer metric readers are found by name from ``BENCHMARK.json``
+(``cells.py``). The run makes its
 data from ``--seed``, builds and warms the index (set-up), measures for
 ``--seconds`` (a job that is under way when the window closes runs to its
 end), then compares what the window produced with the float64 reference.
@@ -93,6 +94,7 @@ def main(argv=None) -> int:
     try:
         cell = cells.load_cell(cells.load_benchmark(ROOT), args.workload,
                                rehearse=args.rehearse, root=ROOT)
+        drive = cells.load_driver(cell.traffic["kind"])
     except cells.CellError as e:
         log(f"error: {e}")
         return 2
@@ -124,7 +126,7 @@ def main(argv=None) -> int:
         run = drivers.Run(cell=cell, seed=args.seed, seconds=args.seconds,
                           trace=bool(args.trace), workdir=workdir,
                           devices=devices, compiles=compiles, log=log)
-        outcome = drivers.DRIVERS[kind](run)
+        outcome = drive(run)
         units = cell.metric_units
         if args.trace:
             metrics = _per_layer(cell, outcome, dev.device_kind)

@@ -10,6 +10,7 @@ import gzip
 import io
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -56,6 +57,8 @@ def test_rehearsal_last_line(workload, trace):
     assert "compiles in window: lowered=0 backend_compiled=0" in err
     assert err.strip().splitlines()[-1].startswith("check ")
     assert "host_user_s=" in err and "involuntary_switches=" in err
+    assert "device_wait_s=" in err and "device_batch_pairs_max=" in err
+    assert re.search(r"^check: (\d+) jobs, 1 distinct results$", err, re.M)
 
 
 def _per_layer_names() -> list:
@@ -84,7 +87,15 @@ def test_refuses_without_a_tpu():
     assert "no TPU" in err
 
 
-def test_missing_files_fail_by_name(tmp_path):
+def test_load_driver_finds_a_kind_by_name():
+    import cells
+    drive = cells.load_driver("join")
+    assert drive.__module__ == "chipbench_kind_join"
+    assert os.path.samefile(drive.__code__.co_filename,
+                            os.path.join(HERE, "kinds", "join.py"))
+
+
+def test_missing_files_fail_by_name(monkeypatch):
     import cells
     bench = cells.load_benchmark(ROOT)
     with pytest.raises(cells.CellError, match="no-such-cell"):
@@ -103,6 +114,21 @@ def test_missing_files_fail_by_name(tmp_path):
                                 "workloads": [name], "moves": "setup_s"})
     with pytest.raises(cells.CellError, match="no_such_metric.join"):
         cells.load_cell(broken, name)
+    with pytest.raises(cells.CellError, match="no_such_kind"):
+        cells.load_driver("no_such_kind")
+    # a traffic mix whose kind has no file: the run exits 2, naming it
+    real = cells.load_cell
+
+    def with_kind(*args, **kw):
+        cell = real(*args, **kw)
+        cell.traffic = dict(cell.traffic, kind="no_such_kind")
+        return cell
+
+    monkeypatch.setattr(cells, "load_cell", with_kind)
+    rc, out, err = _run(["--workload", name, "--seed", "1", "--seconds",
+                         "1", "--trace", "0", "--rehearse"])
+    assert rc == 2 and out == ""
+    assert os.path.join("kinds", "no_such_kind.py") in err
 
 
 def test_bare_checkout_prints_no_result(tmp_path):
@@ -197,6 +223,47 @@ def test_control_is_not_correct(workload):
     for seed in (1, 2, 3):
         check = control.control_check(cell, seed)
         assert not all(c["ok"] for c in check.values()), (seed, check)
+
+
+@pytest.mark.parametrize("faulty_job", [None, 1])
+def test_check_judges_each_distinct_result_once(monkeypatch, faulty_job):
+    """Jobs with the same result share one float64 comparison (the same
+    pairs in another order are the same result), and every job is still
+    judged: a fault in the second of three jobs makes the run not
+    correct."""
+    import types
+
+    import cells
+    import datagen
+    import drivers
+    import reference
+    cell = cells.load_cell(cells.load_benchmark(ROOT), "bigann-50k.selfjoin",
+                           rehearse=True)
+    x = datagen.make(cell.config, SEED)
+    eps = datagen.epsilon_for_avg_neighbors(x, cell.config["avg_neighbors"])
+    ref = reference.Reference(x, eps)
+    pairs = np.concatenate([
+        np.stack([np.full(t.size, r), t], 1)
+        for r, t in enumerate(ref.neighbors(x)) if (t > r).any()])
+    pairs = pairs[pairs[:, 1] > pairs[:, 0]]
+    dists = ref.pair_dist(pairs).astype(np.float32)
+    shuffled = np.random.default_rng(0).permutation(pairs.shape[0])
+    results = [(pairs, dists), (pairs, dists),
+               (pairs[shuffled], dists[shuffled])]
+    if faulty_job is not None:
+        results[faulty_job] = (pairs[::2], dists[::2])
+    calls = []
+    compare = reference.compare_join
+    monkeypatch.setattr(reference, "compare_join",
+                        lambda *a: calls.append(1) or compare(*a))
+    logs = []
+    run = types.SimpleNamespace(cell=cell, seed=SEED, log=logs.append)
+    check = drivers._check_join(run, x, eps, results)
+    distinct = 1 if faulty_job is None else 2
+    assert len(calls) == distinct
+    assert logs == [f"check: 3 jobs, {distinct} distinct results"]
+    assert all(c["ok"] for c in check.values()) == (faulty_job is None), \
+        check
 
 
 def _faulted(monkeypatch, target, name, wrap, workload):
