@@ -5,10 +5,10 @@
 
 The cell, its configuration, traffic mix, the driver of the mix's kind and
 the per-layer metric readers are found by name from ``BENCHMARK.json``
-(``cells.py``). The run makes its
-data from ``--seed``, builds and warms the index (set-up), measures for
-``--seconds`` (a job that is under way when the window closes runs to its
-end), then compares what the window produced with the float64 reference.
+(``cells.py``). The run makes its data from ``--seed``, builds and warms
+the index (set-up), measures for ``--seconds`` (a job that is under way
+when the window closes runs to its end), then compares what the window
+produced with the float64 reference.
 With ``--trace 0`` the result holds the cell's end-to-end metrics; with
 ``--trace 1`` its per-layer metrics, read from the program's spans and
 counters and from the device trace of the window.
