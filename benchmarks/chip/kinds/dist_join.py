@@ -4,16 +4,19 @@ meta, then ``DistributedJoin(...).run(graph)`` on a ``data`` mesh of the
 cell's chips (``core/distributed.py``), which shards each superstep's
 window edges over the chips.
 
-Set-up warms with one whole job: the programs a job runs (the window
-slab's stack and slices at each window size, the verify at each edge
-chunk and each pair capacity that an overflow reaches) follow from the
-plan and the data, and the program exposes no list of them. The window
-runs whole jobs (``drivers``' ``_join_window``); the check compares each
-distinct result with float64.
+Set-up warms every program a job runs, listed from the job's own plan
+(``plan_supersteps``): for each window size and edge count in it, the
+window slab's stack and the slices that harvest it, and the verify of
+each edge chunk at each pair capacity up to the traffic's
+``warm_k_cap_doublings`` raises, through the join's own chunking and
+dispatch. The window runs whole jobs (``drivers``' ``_join_window``); the
+check compares each distinct result with float64.
 """
 from __future__ import annotations
 
 import time
+
+import numpy as np
 
 import drivers
 
@@ -29,6 +32,58 @@ def _job(index, cfg, mesh):
     return once
 
 
+def _warm_dist(run: drivers.Run, index, cfg, mesh) -> int:
+    """Every program of a job, without a job (module docstring). Returns
+    the number of (window size, edge chunk) shapes warmed."""
+    import jax
+
+    from repro.compute import DeviceSlabPool
+    from repro.core import build_bucket_graph
+    from repro.core.distributed import DistributedJoin, plan_supersteps
+    graph = build_bucket_graph(index.meta, cfg)
+    dj = DistributedJoin(index.store, index.meta, cfg, mesh=mesh)
+    steps = plan_supersteps(graph, cfg, dj.cache_buckets, meta=index.meta)
+    sharding = jax.sharding.NamedSharding(
+        dj.mesh, jax.sharding.PartitionSpec("data"))
+    eps2 = float(cfg.epsilon) ** 2
+    base, top = dj._pair_cap, dj.cap * dj.cap
+    k_caps = sorted({min(base << i, top) for i in range(
+        run.cell.traffic["warm_k_cap_doublings"] + 1)})
+    shapes = {}
+    for s in steps:
+        w, e = len(s.bucket_ids), s.edges_local.shape[0]
+        if e:
+            chunks = tuple(ed.shape[0] for _, ed in dj._edge_chunks(
+                np.zeros((e, 2), np.int32), sharding))
+            shapes.setdefault((w, chunks), e)
+    for (w, _), e in sorted(shapes.items()):
+        entries = [dj._fetch(b) for b in range(w)]
+        dj._dev_pool = DeviceSlabPool()
+        for _ in range(2):  # first touch stacks host copies, then harvests
+            slab = _stack_window(dj, entries)
+        edges = np.zeros((e, 2), np.int32)
+        for ce, edges_dev in dj._edge_chunks(edges, sharding):
+            for k_cap in k_caps:
+                dj._pair_cap = k_cap
+                out = dj._dispatch_compact(slab, ce, edges_dev, entries,
+                                           eps2)[3]
+                jax.block_until_ready(out)
+                np.asarray(out[0])
+    return len(shapes)
+
+
+def _stack_window(dj, entries):
+    """A window slab as ``DistributedJoin.run`` stacks it, with the
+    harvest of each first-touch bucket."""
+    import jax.numpy as jnp
+    slab = jnp.stack([dj._dev_pool.operand(b, e[0])
+                      for b, e in enumerate(entries)])
+    for wi in range(len(entries)):
+        if dj._dev_pool.needs_harvest(wi):
+            dj._dev_pool.harvest(wi, slab[wi])
+    return slab
+
+
 def drive(run: drivers.Run) -> drivers.Outcome:
     import jax
 
@@ -41,16 +96,16 @@ def drive(run: drivers.Run) -> drivers.Outcome:
     index, _ = drivers._build(run, x, eps)
     cfg = merge_config(index.build_config, index.query_defaults)
     mesh = jax.make_mesh((chips,), ("data",), devices=run.devices[:chips])
-    once = _job(index, cfg, mesh)
     t_warm = time.perf_counter()
-    once()
+    n = _warm_dist(run, index, cfg, mesh)
     jax.effects_barrier()
-    run.log(f"warm-up: one whole job in "
+    run.log(f"warm-up: {n} window and chunk shapes of the plan in "
             f"{time.perf_counter() - t_warm:.3f}s on mesh "
             f"{dict(mesh.shape)}")
     setup_s = time.perf_counter() - t0
     with drivers._Window(run) as win:
-        results, durations, usage = drivers._join_window(run, once)
+        results, durations, usage = drivers._join_window(
+            run, _job(index, cfg, mesh))
     peak = drivers._memory_peak(run.devices[:chips])
     index.close()
     info = results[-1][1]
@@ -75,6 +130,6 @@ def drive(run: drivers.Run) -> drivers.Outcome:
     rows = x.shape[0] * len(results)
     return drivers.Outcome(
         setup_s=setup_s,
-        metrics={"join_vectors_per_s": rows / sum(durations)},
+        metrics={"join_vectors_per_s.dist": rows / sum(durations)},
         attempted=len(results), failed=0, check=check, counters=counters,
         spans=win.events, trace=win.trace, memory_peak_bytes=peak)

@@ -1,10 +1,6 @@
-"""Device idle share of a distributed join window, in %: 1 - busy /
-window, busy the union of each chip's operations in the profiler trace,
-averaged over the cell's chips."""
+"""Share of the window in which the cell's chips ran no operation, in %,
+averaged over the chips: ``device_idle_share.join``'s reading, for the
+cells that report ``join_vectors_per_s.dist``."""
+import cells
 
-
-def read(ctx):
-    tr = ctx["trace"]
-    if not tr or not tr["chips"] or tr["window_s"] <= 0:
-        return None
-    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
+read = cells.load_reader("device_idle_share.join")

@@ -1,5 +1,5 @@
 """CPU tests of the ``dist_join`` traffic kind (``kinds/dist_join.py``):
-the ``bigann-100k.dist-join`` cell rehearsed end to end on four virtual
+the ``bigann-200k.dist-join`` cell rehearsed end to end on four virtual
 devices, its check of ``correct`` (the control and faults planted in the
 distributed verify come out not correct), and its per-layer readers on a
 device trace recorded on four chips."""
@@ -8,6 +8,7 @@ from __future__ import annotations
 import gzip
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -20,7 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-WORKLOAD = "bigann-100k.dist-join"
+WORKLOAD = "bigann-200k.dist-join"
 SEED = 2 ** 31 + 41
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                  "check"}
@@ -105,11 +106,12 @@ def test_rehearsal_on_four_devices(rehearsals, case):
     assert res["correct"] is True, res["check"]
     assert res["device"]["platform"] == "cpu"
     assert res["device"]["count"] == 4
-    want = {"superstep_ms.dist"} if case == "traced" else {
-        "setup_s", "join_vectors_per_s"}
+    want = {"superstep_ms.dist", "io_wait_share.dist"} if (
+        case == "traced") else {"setup_s", "join_vectors_per_s.dist"}
     assert want <= set(res["metrics"])
     assert "compiles in window: lowered=0 backend_compiled=0" in err
-    assert "warm-up: one whole job" in err and "on mesh {'data': 4}" in err
+    assert re.search(r"warm-up: [1-9]\d* window and chunk shapes of the "
+                     r"plan in \S+ on mesh \{'data': 4\}", err)
     jobs = [ln for ln in err.splitlines() if ln.startswith("job: ")]
     assert len(jobs) == res["attempted"] >= 1
     assert all("verify_dispatches=" in j and "h2d_bytes=" in j
@@ -145,13 +147,34 @@ def _busy_by_grid(events, w0, w1, step):
     return on.sum() * step / 1e9
 
 
+def _reads_slab_to_distances(op: str) -> bool:
+    """The lanes' distance fusion: an output fusion that reads the
+    (window, 2048, 128) slab and writes (lanes, 2048, 2048) float32."""
+    result, _, rest = op.partition(" fusion(")
+    return ("kind=kOutput" in rest and ",2048,2048]" in result
+            and re.search(r"f32\[\d+,2048,128\]", rest) is not None)
+
+
+def test_compact_reader_needs_the_distance_fusion():
+    """Where the distance fusion is not among the trace's longest ops,
+    ``compact_ms.dist`` reads nothing rather than the whole module."""
+    import cells
+    c = {"joins": 1, "verify_dispatches": 8, "compact_overflows": 0}
+    tr = {"chips": 4, "window_s": 1.0, "busy_s": 0.5,
+          "programs": {"jit_verify_edges_compact": 0.1},
+          "device_ops": [["%while.20 = (s32[]) while(s32[] %t)", 0.09]]}
+    read = cells.load_reader("compact_ms.dist")
+    assert read({"spans": [], "trace": tr, "counters": c}) is None
+
+
 def test_dist_readers_on_a_recorded_trace():
-    """A four-chip TPU v5e trace of a ``bigann-100k.dist-join`` window,
-    cut after its first eight verify executions, with counters for that
-    part (the traced job's work, scaled to eight of its executions; the
-    file's ``about`` says how it was made): each ``.dist`` device reader
-    against a count made here from the trace's own events, and the
-    roofline at most 100%."""
+    """A four-chip TPU v5e trace of a distributed-join window at 100,000
+    rows (the same verify programs as the cell's but for the window
+    slab's bucket count), cut after its first eight verify executions,
+    with counters for that part (the traced job's work, scaled to eight
+    of its executions; the file's ``about`` says how it was made): each
+    ``.dist`` device reader against a count made here from the trace's
+    own events, and the roofline at most 100%."""
     import cells
     import devtrace
     with gzip.open(os.path.join(HERE, "testdata",
@@ -161,12 +184,13 @@ def test_dist_readers_on_a_recorded_trace():
         peak = json.load(f)["devices"]["TPU v5 lite"]
     red = devtrace.reduce(raw, chips=4)
     c = raw["counters"]
-    ctx = {"spans": [], "trace": red, "counters": c, "peak": lambda: peak}
+    ctx = {"spans": [], "trace": red, "counters": c,
+           "peak": lambda: peak}
     w0, w1 = devtrace.window_bounds(raw)
     devs = [p for p in raw["planes"] if devtrace._DEVICE_PLANE.match(
         p["name"])]
     assert len(devs) == red["chips"] == 4
-    module_s, busy_s = [], []
+    module_s, dist_s, busy_s = [], [], []
     step = (w1 - w0) / 100000
     for plane in devs:
         mods = [e for ln in plane["lines"] if ln["name"] == "XLA Modules"
@@ -178,6 +202,9 @@ def test_dist_readers_on_a_recorded_trace():
         ops = [e for ln in plane["lines"] if ln["name"] == "XLA Ops"
                for e in ln["events"]]
         busy_s.append(_busy_by_grid(ops, w0, w1, step))
+        dist_s.append(sum(min(s + d, w1) - max(s, w0) for n, s, d in ops
+                          if _reads_slab_to_distances(n)
+                          and s < w1 and s + d > w0) / 1e9)
     executions = c["joins"] * (c["verify_dispatches"]
                                + c["compact_overflows"])
     assert executions == raw["executions"]
@@ -191,8 +218,28 @@ def test_dist_readers_on_a_recorded_trace():
     assert roofline == pytest.approx(100 * least / np.mean(module_s),
                                      rel=1e-9)
     assert 0 < roofline <= 100
+    compact = cells.load_reader("compact_ms.dist")(ctx)
+    assert min(dist_s) > 0
+    assert compact == pytest.approx(
+        1e3 * (np.mean(module_s) - np.mean(dist_s)) / executions, rel=1e-9)
+    assert 0 < compact < ms
     idle = cells.load_reader("device_idle_share.dist")(ctx)
     assert idle == pytest.approx(
         100 * (1 - np.mean(busy_s) / ((w1 - w0) / 1e9)),
         abs=100 * 2 * step / (w1 - w0))
     assert 0 < idle < 100
+
+
+def test_span_readers_of_the_superstep_loop():
+    """``superstep_ms.dist`` is the mean ``dist.superstep`` span and
+    ``io_wait_share.dist`` the share of those spans spent in
+    ``dist.prefetch``; other spans do not count."""
+    import cells
+    spans = [{"name": "dist.superstep", "ph": "X", "ts": 0.0, "dur": 30e3},
+             {"name": "dist.prefetch", "ph": "X", "ts": 5e3, "dur": 3e3},
+             {"name": "dist.superstep", "ph": "X", "ts": 31e3, "dur": 20e3},
+             {"name": "dist.prefetch", "ph": "X", "ts": 33e3, "dur": 2e3},
+             {"name": "jit.compile", "ph": "X", "ts": 60e3, "dur": 9e3}]
+    ctx = {"spans": spans, "trace": None, "counters": {}}
+    assert cells.load_reader("superstep_ms.dist")(ctx) == pytest.approx(25)
+    assert cells.load_reader("io_wait_share.dist")(ctx) == pytest.approx(10)
