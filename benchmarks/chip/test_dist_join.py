@@ -168,13 +168,12 @@ def test_compact_reader_needs_the_distance_fusion():
 
 
 def test_dist_readers_on_a_recorded_trace():
-    """A four-chip TPU v5e trace of a distributed-join window at 100,000
-    rows (the same verify programs as the cell's but for the window
-    slab's bucket count), cut after its first eight verify executions,
-    with counters for that part (the traced job's work, scaled to eight
-    of its executions; the file's ``about`` says how it was made): each
-    ``.dist`` device reader against a count made here from the trace's
-    own events, and the roofline at most 100%."""
+    """A four-chip TPU v5e trace of a ``bigann-200k.dist-join`` window,
+    cut after its first six verify executions, with the program's
+    superstep and prefetch spans and counters for that part (the traced
+    job's work, scaled to six of its executions; the file's ``about``
+    says how it was made): each ``.dist`` reader against a count made
+    here from the trace's own events, and the roofline at most 100%."""
     import cells
     import devtrace
     with gzip.open(os.path.join(HERE, "testdata",
@@ -184,13 +183,13 @@ def test_dist_readers_on_a_recorded_trace():
         peak = json.load(f)["devices"]["TPU v5 lite"]
     red = devtrace.reduce(raw, chips=4)
     c = raw["counters"]
-    ctx = {"spans": [], "trace": red, "counters": c,
+    ctx = {"spans": raw["spans"], "trace": red, "counters": c,
            "peak": lambda: peak}
     w0, w1 = devtrace.window_bounds(raw)
     devs = [p for p in raw["planes"] if devtrace._DEVICE_PLANE.match(
         p["name"])]
     assert len(devs) == red["chips"] == 4
-    module_s, dist_s, busy_s = [], [], []
+    module_s, dist_s, busy_s, ops_s = [], [], [], {}
     step = (w1 - w0) / 100000
     for plane in devs:
         mods = [e for ln in plane["lines"] if ln["name"] == "XLA Modules"
@@ -205,6 +204,10 @@ def test_dist_readers_on_a_recorded_trace():
         dist_s.append(sum(min(s + d, w1) - max(s, w0) for n, s, d in ops
                           if _reads_slab_to_distances(n)
                           and s < w1 and s + d > w0) / 1e9)
+        for n, s, d in ops:
+            if s < w1 and s + d > w0:
+                ops_s[n] = ops_s.get(n, 0.0) + (min(s + d, w1)
+                                                - max(s, w0)) / 1e9
     executions = c["joins"] * (c["verify_dispatches"]
                                + c["compact_overflows"])
     assert executions == raw["executions"]
@@ -218,11 +221,27 @@ def test_dist_readers_on_a_recorded_trace():
     assert roofline == pytest.approx(100 * least / np.mean(module_s),
                                      rel=1e-9)
     assert 0 < roofline <= 100
-    compact = cells.load_reader("compact_ms.dist")(ctx)
+    # The distances take 0.3% of the module here, so in six executions
+    # their fusion falls out of the ten longest ops that the reduction
+    # keeps, and the reader reads nothing; given every op, it reads the
+    # module less that fusion.
+    read_compact = cells.load_reader("compact_ms.dist")
     assert min(dist_s) > 0
+    every_op = dict(red, device_ops=sorted(ops_s.items(),
+                                           key=lambda kv: -kv[1]))
+    compact = read_compact(dict(ctx, trace=every_op))
     assert compact == pytest.approx(
         1e3 * (np.mean(module_s) - np.mean(dist_s)) / executions, rel=1e-9)
     assert 0 < compact < ms
+    assert read_compact(ctx) in (None, pytest.approx(compact, rel=1e-9))
+    steps = [s["dur"] for s in raw["spans"] if s["name"] == "dist.superstep"]
+    reads = [s["dur"] for s in raw["spans"] if s["name"] == "dist.prefetch"]
+    assert steps and reads
+    assert cells.load_reader("superstep_ms.dist")(ctx) == pytest.approx(
+        np.mean(steps) / 1e3, rel=1e-12)
+    io = cells.load_reader("io_wait_share.dist")(ctx)
+    assert io == pytest.approx(100 * sum(reads) / sum(steps), rel=1e-12)
+    assert 0 < io < 100
     idle = cells.load_reader("device_idle_share.dist")(ctx)
     assert idle == pytest.approx(
         100 * (1 - np.mean(busy_s) / ((w1 - w0) / 1e9)),
