@@ -9,14 +9,14 @@ path; both produce byte-identical results and are selected by
 ``JoinConfig.compute_mode``. See README.md for the staging pipeline and
 slab-pool lifecycle.
 """
-from repro.compute.engine import (PAIR_CAP_INIT, DeviceVerifyEngine,
-                                  HostVerifyEngine, RoutedVerifyEngine,
+from repro.compute.engine import (PAIR_CAP_INIT, DeviceQueryVerify,
+                                  DeviceVerifyEngine, HostVerifyEngine,
+                                  QueryLaneState, RoutedVerifyEngine,
                                   compact_batch, compact_pairs,
-                                  make_verify_engine, next_pow2,
-                                  query_verify_compact)
+                                  make_verify_engine, next_pow2)
 from repro.compute.slab_pool import DeviceSlabPool
 
-__all__ = ["DeviceSlabPool", "DeviceVerifyEngine", "HostVerifyEngine",
-           "PAIR_CAP_INIT", "RoutedVerifyEngine", "compact_batch",
-           "compact_pairs", "make_verify_engine", "next_pow2",
-           "query_verify_compact"]
+__all__ = ["DeviceQueryVerify", "DeviceSlabPool", "DeviceVerifyEngine",
+           "HostVerifyEngine", "PAIR_CAP_INIT", "QueryLaneState",
+           "RoutedVerifyEngine", "compact_batch", "compact_pairs",
+           "make_verify_engine", "next_pow2"]

@@ -49,12 +49,14 @@ search covers the batch's real total and not every lane's worst case.
 It adapts: a batch whose total overflows the current capacity is
 re-compacted from its still-resident d2/mask at the next power of two
 (the kernel output was sized too small, not wrong), and the larger
-capacity sticks for later batches. The query and distributed paths keep
-one capacity per lane (``compact_pairs``).
+capacity sticks for later batches. ``DeviceQueryVerify`` runs a query
+wave's probed buckets through the same ``device_verify``; the
+distributed path keeps one capacity per lane (``compact_pairs``).
 """
 from __future__ import annotations
 
 import functools
+import threading
 import time
 
 import jax
@@ -63,7 +65,6 @@ import numpy as np
 
 from repro.compute.slab_pool import DeviceSlabPool
 from repro.kernels import ops as kops
-from repro.kernels import ref
 from repro.obs import compiles, get_tracer
 
 PAIR_CAP_INIT = 1024  # initial per-edge compaction capacity (pairs)
@@ -241,27 +242,6 @@ def device_verify(na, nb, intra, *slabs, eps: float, k_cap: int,
     # the stacked operands come back as outputs so the engine can harvest
     # first-touch lanes into the device slab pool once the batch lands
     return counts, out_r, out_c, out_d, u, v
-
-
-@functools.partial(jax.jit, static_argnames=("eps2", "k_cap"))
-def query_verify_compact(q_block: jax.Array, qidx: jax.Array, nq,
-                         slab: jax.Array, eps2: float, k_cap: int):
-    """Online point-query verify (``DiskJoinIndex.execute_probes``,
-    ``compute_mode="device"``): the wave's query block is staged on-device
-    ONCE and each probed bucket's verify gathers its member rows from it.
-    ``qidx`` is pow2-padded (bounded recompiles); ``nq`` live entries —
-    padded rows repeat query 0 and are masked out by the row count.
-    Returns compacted (counts (1,), q-rows, cols, d²) against the
-    (capacity, dim) bucket slab."""
-    with jax.named_scope("verify.stack"):
-        qs = jnp.take(q_block, qidx, axis=0)         # (Qp, d)
-    with jax.named_scope("verify.kernel"):
-        d2 = ref.pairwise_l2(qs, slab)[None]         # (1, Qp, cap)
-        mask = d2 <= eps2
-    na = jnp.reshape(nq, (1,)).astype(jnp.int32)
-    nb = jnp.full((1,), slab.shape[0], jnp.int32)
-    intra = jnp.zeros((1,), bool)
-    return compact_pairs(d2, mask, na, nb, intra, k_cap)
 
 
 class _EngineBase:
@@ -630,6 +610,216 @@ class DeviceVerifyEngine(_EngineBase):
         self._batch.clear()
         self._inflight = None
         self.pool.clear()
+
+
+class QueryLaneState:
+    """What an index session's device query path keeps between waves:
+    the query rows a lane holds, the sticky pair capacity, host staging
+    buffers and the device-resident zero lanes that pad a dispatch.
+
+    ``rows`` is the session's one query row count (operand u of every
+    query lane), so lane shapes never follow a wave's size."""
+
+    def __init__(self, rows: int, capacity_rows: int, dim: int):
+        self.rows = int(rows)
+        self.cap = int(capacity_rows)
+        self.dim = int(dim)
+        # ~8 pairs per bucket row, as the join's batches start
+        self.pair_cap = next_pow2(max(PAIR_CAP_INIT, 8 * self.cap))
+        self._lock = threading.Lock()
+        self._staging: list[tuple[np.ndarray, np.ndarray]] = []
+        self._pad = None
+
+    def batch_cap(self, lanes: int, lane_cap: int = 0,
+                  total: int = 0) -> int:
+        """Pair capacity of a ``lanes``-lane batch: the sticky capacity,
+        a planned per-lane floor and ``total`` pairs, as a power of two,
+        never more than the batch's flags."""
+        k = max(self.pair_cap, lane_cap * lanes, next_pow2(total))
+        return min(k, lanes * self.rows * self.cap)
+
+    def raise_cap(self, total: int) -> None:
+        with self._lock:
+            self.pair_cap = max(self.pair_cap, next_pow2(total))
+
+    def take_staging(self, lanes: int) -> tuple[np.ndarray, np.ndarray]:
+        with self._lock:
+            for i, (u, _) in enumerate(self._staging):
+                if u.shape[0] == lanes:
+                    return self._staging.pop(i)
+        return (np.zeros((lanes, self.rows, self.dim), np.float32),
+                np.zeros((lanes, self.cap, self.dim), np.float32))
+
+    def give_back(self, buf: tuple[np.ndarray, np.ndarray]) -> None:
+        with self._lock:
+            self._staging.append(buf)
+
+    def pad_lanes(self):
+        """Zero (u, v) operands kept on the device: a dispatch's pad
+        lanes (na = nb = 0) cross no bytes."""
+        with self._lock:
+            if self._pad is None:
+                self._pad = (
+                    jax.device_put(np.zeros((self.rows, self.dim),
+                                            np.float32)),
+                    jax.device_put(np.zeros((self.cap, self.dim),
+                                            np.float32)))
+            return self._pad
+
+
+class DeviceQueryVerify:
+    """Device verify of one query wave's probed buckets
+    (``DiskJoinIndex.execute_probes``, ``compute_mode="device"``).
+
+    Each probed bucket becomes a lane of ``device_verify``: operand u
+    holds up to ``state.rows`` of its probing queries (more probers take
+    more lanes), operand v the bucket's padded slab, ``na`` the live
+    probers, ``nb`` the bucket's rows, ``intra`` False. Up to
+    ``verify_batch`` lanes ride one dispatch, padded to a power of two
+    with device-resident zero lanes. Lanes are staged into one of two
+    host buffers while the other's batch runs, and a batch is collected
+    just before the next one goes out. The pair capacity is pooled over
+    the batch (``compact_batch``); a batch whose total overflows it is
+    re-dispatched at ``next_pow2(total)``, and the raise sticks in
+    ``state`` for the session. ``finish`` returns the wave's (query,
+    id, distance) triples, the sqrt taken on the host.
+    """
+
+    def __init__(self, state: QueryLaneState, Q: np.ndarray, *,
+                 epsilon: float, verify_batch: int, use_pallas: bool,
+                 lane_cap: int = 0, pstats=None, tracer=None):
+        self.s = state
+        self.Q = Q
+        self.eps = float(epsilon)
+        self.verify_batch = max(1, int(verify_batch))
+        self.use_pallas = bool(use_pallas)
+        self.lane_cap = next_pow2(lane_cap) if lane_cap else 0
+        self.pstats = pstats
+        self.tracer = tracer if tracer is not None else get_tracer()
+        compiles.install()
+        self._bufs = [state.take_staging(self.verify_batch),
+                      state.take_staging(self.verify_batch)]
+        self._cur = 0
+        self._lanes: list[tuple] = []    # (query rows, ids, bucket rows)
+        self._inflight: tuple | None = None
+        self._out: list[tuple] = []
+        self.lanes = self.dispatches = self.overflows = 0
+        self._t_first: float | None = None
+
+    def _stat(self, field: str, amount) -> None:
+        if self.pstats is not None:
+            self.pstats.add(field, amount)
+
+    def add(self, qidx, vecs: np.ndarray, ids: np.ndarray, n: int) -> None:
+        """Stage bucket rows ``vecs[:n]`` (ids ``ids[:n]``) against the
+        wave's queries ``qidx``. ``vecs`` may be a recyclable pool slab:
+        it is copied before this returns."""
+        qidx = np.asarray(qidx, np.int64)
+        ids = np.array(ids[:n])
+        for c0 in range(0, qidx.size, self.s.rows):
+            chunk = qidx[c0:c0 + self.s.rows]
+            u, v = self._bufs[self._cur]
+            e = len(self._lanes)
+            u[e, :chunk.size] = self.Q[chunk]
+            v[e, :n] = vecs[:n]   # rows past nb are never kept
+            self._lanes.append((chunk, ids, n))
+            if len(self._lanes) == self.verify_batch:
+                self._flush()
+
+    def _flush(self) -> None:
+        self._collect()
+        self._dispatch()
+
+    def _dispatch(self) -> None:
+        t0 = time.perf_counter()
+        if self._t_first is None:
+            self._t_first = t0
+        lanes, self._lanes = self._lanes, []
+        E, B = len(lanes), next_pow2(len(lanes))
+        u, v = self._bufs[self._cur]
+        self._cur ^= 1
+        pu, pv = self.s.pad_lanes()
+        slabs = ([u[i] for i in range(E)] + [pu] * (B - E)
+                 + [v[i] for i in range(E)] + [pv] * (B - E))
+        na = np.zeros(B, np.int32)
+        nb = np.zeros(B, np.int32)
+        for i, (chunk, _, n) in enumerate(lanes):
+            na[i], nb[i] = chunk.size, n
+        intra = np.zeros(B, bool)
+        k_cap = self.s.batch_cap(B, self.lane_cap)
+        out = device_verify(na, nb, intra, *slabs, eps=self.eps,
+                            k_cap=k_cap, use_pallas=self.use_pallas)
+        sent = E * (self.s.rows + self.s.cap) * self.s.dim * 4
+        self.lanes += E
+        self.dispatches += 1
+        self._stat("query_device_batches", 1)
+        self._stat("query_distance_computations",
+                   int(np.dot(na.astype(np.int64), nb)))
+        self._stat("h2d_transfers", 2 * E)
+        self._sent(sent)
+        self._inflight = (out, slabs, na, nb, intra, lanes, k_cap, sent)
+
+    def _sent(self, nbytes: int) -> None:
+        self._stat("query_h2d_bytes", nbytes)
+        self._stat("h2d_bytes", nbytes)
+
+    def _collect(self) -> None:
+        if self._inflight is None:
+            return
+        out, slabs, na, nb, intra, lanes, k_cap, sent = self._inflight
+        self._inflight = None
+        counts = np.asarray(out[0])
+        total = int(counts.sum())
+        if total > k_cap:
+            # the output was sized too small, not wrong: re-dispatch
+            # the still-staged lanes at the next pow2, which sticks
+            self.s.raise_cap(total)
+            k_cap = self.s.batch_cap(na.size, self.lane_cap, total)
+            out = device_verify(na, nb, intra, *slabs, eps=self.eps,
+                                k_cap=k_cap, use_pallas=self.use_pallas)
+            counts = np.asarray(out[0])
+            self.dispatches += 1
+            self.overflows += 1
+            self._stat("query_compact_overflows", 1)
+            self._sent(sent)
+        rows = np.asarray(out[1])[:total]
+        cols = np.asarray(out[2])[:total]
+        dists = np.sqrt(np.asarray(out[3])[:total])
+        self._stat("d2h_bytes", counts.nbytes + 3 * 4 * k_cap)
+        off = 0
+        for (chunk, ids, _), k in zip(lanes, counts.tolist()):
+            if k:
+                lane = slice(off, off + k)
+                self._out.append((chunk[rows[lane]], ids[cols[lane]],
+                                  dists[lane]))
+                off += k
+
+    def finish(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Dispatch what is staged, collect everything, and return the
+        wave's (query index, id, float32 distance) triples."""
+        if self._lanes:
+            self._flush()
+        self._collect()
+        if self._t_first is not None:
+            self.tracer.complete(
+                "query.verify", self._t_first,
+                time.perf_counter() - self._t_first, lanes=self.lanes,
+                dispatches=self.dispatches, overflows=self.overflows)
+        self.close()
+        if not self._out:
+            return (np.zeros(0, np.int64), np.zeros(0, np.int64),
+                    np.zeros(0, np.float32))
+        q, ids, d = zip(*self._out)
+        return (np.concatenate(q), np.concatenate(ids).astype(np.int64),
+                np.concatenate(d).astype(np.float32))
+
+    def close(self) -> None:
+        """Hand the staging buffers back (idempotent); an unfinished
+        wave's in-flight batch is dropped."""
+        self._inflight = None
+        for buf in self._bufs:
+            self.s.give_back(buf)
+        self._bufs = []
 
 
 class RoutedVerifyEngine:

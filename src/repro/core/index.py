@@ -47,6 +47,7 @@ from collections import OrderedDict
 
 import numpy as np
 
+from repro.compute import DeviceQueryVerify, QueryLaneState, next_pow2
 from repro.core import ordering
 from repro.core.bipartite import bipartite_join
 from repro.core.bucket_graph import build_bucket_graph
@@ -70,6 +71,16 @@ from repro.plan import (SKETCH_FILE, CardinalityEstimator, CostModel,
 from repro.store.striped_store import StripedBucketedVectorStore
 from repro.store.vector_store import BucketedVectorStore, FlatVectorStore
 
+
+def _pow2_range(lo: int, hi: int) -> list[int]:
+    """Powers of two from ``lo`` to ``hi`` (both powers of two)."""
+    out = []
+    while lo <= hi:
+        out.append(lo)
+        lo *= 2
+    return out
+
+
 MANIFEST_NAME = "diskjoin_index.json"
 MANIFEST_FORMAT = "diskjoin-index/v1"
 # serving fast-restart snapshot: which buckets were warm at close()
@@ -77,6 +88,8 @@ RESIDENCY_NAME = "residency.json"
 # pool slabs the query warm cache always leaves free (liveness headroom
 # for concurrent batch joins and for the queries' own transient reads)
 _WARM_RESERVE = 2
+# query rows a device query lane holds until warm_queries sets a wave size
+DEFAULT_QUERY_ROWS = 64
 
 
 class DiskJoinIndex:
@@ -113,6 +126,11 @@ class DiskJoinIndex:
         self._pool_lock = threading.Lock()
         self._center_index = None
         self._center_lock = threading.Lock()
+        # device query path (compute_mode="device"): lane rows, sticky
+        # pair capacity and staging buffers, made on first use
+        self._query_lanes: QueryLaneState | None = None
+        self._query_rows = DEFAULT_QUERY_ROWS
+        self._query_lock = threading.Lock()
         self._graph_cache: dict = {}
         self._order_cache: dict = {}
         # warm point-query cache: bucket -> (pool slot, rows); each entry
@@ -876,16 +894,37 @@ class DiskJoinIndex:
                     acc_d[qi].append(np.sqrt(d2[row][m])
                                      .astype(np.float32))
 
+        dev = None
         if compute == "device":
-            verify = self._make_device_verify(
-                Q, probe, eps, acc_ids, acc_d, live_rows=live_rows,
-                k_cap_init=(wplan.k_cap if wplan is not None else None))
+            dev = DeviceQueryVerify(
+                self._query_lane_state(), Q, epsilon=eps,
+                verify_batch=cfg.verify_batch, use_pallas=cfg.use_pallas,
+                lane_cap=wplan.k_cap if wplan is not None else 0,
+                pstats=self.stats, tracer=self._tracer())
+
+            def verify(b: int, vecs: np.ndarray, ids_: np.ndarray,
+                       n: int) -> None:
+                qidx = live_rows(b)
+                if qidx:
+                    dev.add(qidx, vecs, ids_, n)
         skip = None
         if cancel is not None:
             def skip(b: int) -> bool:
                 return not live_rows(b)
-        self._read_and_verify(self._sorted_by_layout(list(probe)), cfg,
-                              verify, skip=skip)
+        try:
+            self._read_and_verify(self._sorted_by_layout(list(probe)), cfg,
+                                  verify, skip=skip)
+            if dev is not None:
+                q, ids, d = dev.finish()
+                order = np.argsort(q, kind="stable")
+                cuts = np.flatnonzero(np.diff(q[order])) + 1
+                for part in np.split(order, cuts):
+                    if part.size:
+                        acc_ids[q[part[0]]].append(ids[part])
+                        acc_d[q[part[0]]].append(d[part])
+        finally:
+            if dev is not None:
+                dev.close()
         self.stats.add("queries", Q.shape[0])
         self._maybe_snapshot_residency()
 
@@ -898,82 +937,50 @@ class DiskJoinIndex:
                 out.append((np.zeros(0, np.int64), np.zeros(0, np.float32)))
         return out
 
-    def _make_device_verify(self, Q: np.ndarray, probe: dict, eps: float,
-                            acc_ids: list, acc_d: list, live_rows=None,
-                            k_cap_init: int | None = None):
-        """Device verify for a probe wave (``compute_mode="device"``):
-        the wave's query block crosses H2D ONCE, each probed bucket's
-        padded slab once, and the kernel hands back compacted
-        (query row, bucket row, distance) triples — no per-bucket host
-        distance matrix. Both query paths compute d² in float32 (same
-        formulation, see ``_execute_probes_inner``), so host/device
-        results agree up to f32 matmul accumulation order — a few ulps
-        on d², which only borderline pairs within that tolerance of ε
-        can notice (the batch-join engines are byte-identical — both
-        take d² from the same jitted program).
+    def _query_lane_state(self) -> QueryLaneState:
+        with self._query_lock:
+            st = self._query_lanes
+            if st is None or st.rows != self._query_rows:
+                st = self._query_lanes = QueryLaneState(
+                    self._query_rows, self.bucket_capacity, self.dim)
+            return st
 
-        ``k_cap_init`` seeds the compaction capacity from the wave
-        plan's estimate upper bound (``plan_mode="on"``) instead of the
-        fixed 256; overflow re-dispatch remains as the counted fallback.
-        """
+    def warm_queries(self, wave_size: int, *, k_cap_doublings: int = 2,
+                     **overrides) -> None:
+        """Compile, before serving, every program a wave of up to
+        ``wave_size`` queries at these query-time parameters can reach,
+        and fix the device query lanes at ``wave_size`` rounded up to 8
+        rows (``QueryScheduler``'s ``wave_size``).
+
+        Compiles the centre search at each padded wave size and, in
+        ``compute_mode="device"`` (or "auto"), ``device_verify`` at each
+        power-of-two lane count up to ``verify_batch``, at the session's
+        pair capacity and ``k_cap_doublings`` raises of it. A wave of any
+        size and mix of probers then compiles nothing, unless its batch
+        overflows past the last raise warmed."""
         import jax
-        import jax.numpy as jnp
 
-        from repro.compute import next_pow2, query_verify_compact
-
-        eps2 = float(eps) * float(eps)
-        cap = self.bucket_capacity
-        q_dev = jax.device_put(np.array(Q, np.float32))  # staged ONCE
-        self.stats.add("h2d_transfers", 1)
-        self.stats.add("h2d_bytes", int(Q.nbytes))
-        state = {"first": True, "k_cap": int(k_cap_init or 256)}
-
-        def verify(b: int, vecs: np.ndarray, ids_: np.ndarray,
-                   n: int) -> None:
-            rows_alive = (probe[b] if live_rows is None
-                          else live_rows(b))
-            if not rows_alive:
-                return
-            if state["first"]:
-                state["first"] = False
-            else:
-                # every verify after the first reuses the staged block
-                # a per-bucket staging baseline would re-transfer
-                self.stats.add("device_slab_hits", 1)
-                self.stats.add("h2d_transfers_saved", 1)
-            slab = vecs
-            if slab.shape[0] != cap:  # fallback reads come unpadded
-                slab = np.concatenate(
-                    [slab, np.full((cap - slab.shape[0], slab.shape[1]),
-                                   PAD_COORD, np.float32)])
-            slab_dev = jax.device_put(np.array(slab, np.float32))
-            self.stats.add("h2d_transfers", 1)
-            self.stats.add("h2d_bytes", int(slab.nbytes))
-            qidx = np.asarray(rows_alive, np.int32)
-            nq = qidx.size
-            idx = np.zeros(next_pow2(nq), np.int32)
-            idx[:nq] = qidx
-            idx_dev = jnp.asarray(idx)
-            while True:
-                counts, r, c, d = query_verify_compact(
-                    q_dev, idx_dev, nq, slab_dev, eps2, state["k_cap"])
-                k = int(np.asarray(counts)[0])
-                if k <= state["k_cap"]:
-                    break
-                state["k_cap"] = next_pow2(k)
-            if k == 0:
-                return
-            qrows = np.asarray(r)[0, :k]
-            cols = np.asarray(c)[0, :k]
-            dists = np.sqrt(np.asarray(d)[0, :k])
-            lids = ids_[:n]
-            for row in np.unique(qrows):
-                sel = qrows == row
-                qi = int(qidx[row])
-                acc_ids[qi].append(lids[cols[sel]].astype(np.int64))
-                acc_d[qi].append(dists[sel].astype(np.float32))
-
-        return verify
+        from repro.compute.engine import device_verify
+        cfg = self._resolve(overrides)
+        self._query_rows = -(-max(1, int(wave_size)) // 8) * 8
+        for n in _pow2_range(8, max(8, next_pow2(wave_size))):
+            self._candidate_buckets(np.zeros((n, self.dim), np.float32),
+                                    cfg)
+        if cfg.compute_mode == "host":
+            return
+        st = self._query_lane_state()
+        u = np.zeros((st.rows, self.dim), np.float32)
+        v = np.zeros((st.cap, self.dim), np.float32)
+        for lanes in _pow2_range(1, next_pow2(cfg.verify_batch)):
+            zero = np.zeros(lanes, np.int32)
+            caps = {st.batch_cap(lanes, total=st.pair_cap << i)
+                    for i in range(k_cap_doublings + 1)}
+            for k_cap in sorted(caps):
+                jax.block_until_ready(device_verify(
+                    zero, zero, np.zeros(lanes, bool),
+                    *([u] * lanes + [v] * lanes),
+                    eps=float(cfg.epsilon), k_cap=k_cap,
+                    use_pallas=cfg.use_pallas))
 
     def _sorted_by_layout(self, buckets: list[int]) -> list[int]:
         """Order an ad-hoc bucket set by disk placement, so a wave's
@@ -995,10 +1002,17 @@ class DiskJoinIndex:
                 self._center_index = make_center_index(self.meta.centers)
         eps = float(cfg.epsilon)
         L = min(cfg.max_candidates, self.meta.num_buckets)
+        # pad to a power of two of queries (at least 8): one centre-search
+        # program per padded size, which warm_queries compiles
+        nq = Q.shape[0]
+        pad = max(8, next_pow2(nq)) - nq
+        if pad:
+            Q = np.concatenate([Q, np.zeros((pad, Q.shape[1]), Q.dtype)])
         d2, cand = self._center_index.search(Q, L)
+        d2, cand = d2[:nq], cand[:nq]
         dists = np.sqrt(np.maximum(d2, 0.0))
         out = []
-        for qi in range(Q.shape[0]):
+        for qi in range(nq):
             ids, dd = cand[qi], dists[qi]
             ok = np.isfinite(dd)
             ids, dd = ids[ok], dd[ok]
@@ -1089,8 +1103,9 @@ class DiskJoinIndex:
                     retries=cfg.io_retries,
                     backoff_s=cfg.io_retry_backoff_s, stats=self.stats)
                 if tr.enabled:
-                    tr.complete("io.read", t0, time.perf_counter() - t0,
-                                buckets=1, src="query")
+                    dt = time.perf_counter() - t0
+                    tr.complete("io.read", t0, dt, buckets=1, src="query")
+                    tr.complete("query.io_wait", t0, dt)
                 self.stats.add("query_fallback_reads", 1)
                 verify(b, vecs, ids, n)
                 continue
@@ -1102,8 +1117,9 @@ class DiskJoinIndex:
                 retries=cfg.io_retries,
                 backoff_s=cfg.io_retry_backoff_s, stats=self.stats)
             if tr.enabled:
-                tr.complete("io.read", t0, time.perf_counter() - t0,
-                            buckets=1, src="query")
+                dt = time.perf_counter() - t0
+                tr.complete("io.read", t0, dt, buckets=1, src="query")
+                tr.complete("query.io_wait", t0, dt)
             self.stats.add("query_reads", 1)
             try:
                 verify(b, pool.vecs(slot), pool.ids(slot), n)
@@ -1118,6 +1134,7 @@ class DiskJoinIndex:
         cancellation here skips only the verify fan-out — the slab still
         lands (and stays warm for later waves), it just isn't scanned."""
         from repro.io import SchedulePrefetcher
+        tr = self._tracer()
         pf = SchedulePrefetcher(
             self.store, misses, pool, lookahead=cfg.io_lookahead,
             num_threads=cfg.io_threads, stats=self.stats,
@@ -1127,7 +1144,11 @@ class DiskJoinIndex:
             retry_backoff_s=cfg.io_retry_backoff_s)
         try:
             for _ in misses:
+                t0 = time.perf_counter() if tr.enabled else 0.0
                 b, slot, n = pf.pop_next()
+                if tr.enabled:
+                    tr.complete("query.io_wait", t0,
+                                time.perf_counter() - t0)
                 self.stats.add("query_reads", 1)
                 try:
                     if skip is None or not skip(b):

@@ -53,6 +53,12 @@ class PipelineStats:
     query_reads: int = 0          # bucket reads issued for queries (pooled)
     query_warm_hits: int = 0      # query candidates served from warm slabs
     query_fallback_reads: int = 0  # unpooled reads (pool fully contended)
+    # device query verify (compute.DeviceQueryVerify): lanes of a wave's
+    # probed buckets batched into device_verify dispatches
+    query_device_batches: int = 0       # device_verify dispatches
+    query_compact_overflows: int = 0    # batches re-dispatched larger
+    query_distance_computations: int = 0  # live probers x bucket rows
+    query_h2d_bytes: int = 0            # query and slab bytes sent
     # wave-batched serving (repro.serve.QueryScheduler): concurrent
     # queries probing the same bucket in one wave share a single read
     waves: int = 0                   # scheduler waves executed

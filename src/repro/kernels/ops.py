@@ -76,18 +76,21 @@ def _verify_pairs_ref(u, v, eps2: float):
 
 def verify_pairs_batch(u, v, eps: float, *, use_pallas: bool = False,
                        block: int = 128):
-    """Batched verify: (E, cap, d) × (E, cap, d) → (d2, mask), (E, cap, cap).
+    """Batched verify: (E, M, d) × (E, N, d) → (d2, mask), (E, M, N).
 
     ONE dispatch for the whole edge batch — the Pallas path rides a
     leading batch grid dimension (``pairwise_l2_threshold_batched``)
     instead of E separate jit calls, and the reference path is the
     vmapped oracle. Both engines (``repro.compute``) consume this, so
-    host and device compute modes see bitwise-identical d2.
+    host and device compute modes see bitwise-identical d2. A join lane
+    pairs two bucket slabs (M = N = capacity); a query lane pairs a
+    wave's query rows with one bucket slab (M ≠ N).
     """
     eps2 = float(eps) ** 2
     if not use_pallas:
         return _verify_pairs_ref(u, v, eps2)
-    e, m, d = u.shape
+    _, m, d = u.shape
+    n = v.shape[1]
     # the kernel clamps blocks to the dims, so only dims above `block`
     # that aren't multiples of it need padding
     if d > block and d % block:
@@ -95,14 +98,17 @@ def verify_pairs_batch(u, v, eps: float, *, use_pallas: bool = False,
         u = jnp.pad(u, ((0, 0), (0, 0), (0, dp - d)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, dp - d)))
     mp = _round_up(m, block) if (m > block and m % block) else m
+    np_ = _round_up(n, block) if (n > block and n % block) else n
+    # pad rows far away so they can never pass the ε² threshold; each
+    # operand to its own block multiple, sliced back below
     if mp != m:
-        # pad rows far away so they can never pass the ε² threshold
         u = jnp.pad(u, ((0, 0), (0, mp - m), (0, 0)), constant_values=1e15)
-        v = jnp.pad(v, ((0, 0), (0, mp - m), (0, 0)), constant_values=1e15)
+    if np_ != n:
+        v = jnp.pad(v, ((0, 0), (0, np_ - n), (0, 0)), constant_values=1e15)
     d2, mask = _pairwise_kernel.pairwise_l2_threshold_batched(
         u, v, eps2, interpret=interpret_mode())
-    if mp != m:
-        d2, mask = d2[:, :m, :m], mask[:, :m, :m]
+    if mp != m or np_ != n:
+        d2, mask = d2[:, :m, :n], mask[:, :m, :n]
     return d2, mask.astype(bool)
 
 

@@ -14,7 +14,7 @@ The estimate is a binomial proportion, so its error bars are calibrated
 by construction: ``est_edges`` returns Wilson-score intervals at the
 estimator's ``z`` (default 2 ≈ 95%), and the *upper* bound is what the
 planner sizes hard capacities from (``compact_pairs`` lane capacity,
-``query_verify_compact`` k_cap) — a bound that is allowed to be loose
+the device query path's per-lane floor) — a bound that is allowed to be loose
 but must rarely be exceeded, because exceeding it costs an overflow
 re-dispatch (a recompile), while looseness only costs output-buffer
 bytes.

@@ -506,13 +506,181 @@ class TestQueryDevice:
             for (ih, dh), (idv, ddv) in zip(host, dev):
                 oh, od = np.argsort(ih), np.argsort(idv)
                 assert np.array_equal(np.sort(ih), np.sort(idv))
-                # device distances are f32 (host is f64): close, not
-                # byte-identical — documented in _make_device_verify
+                # both take d² in float32, by different formulations
+                # (the host accumulates in float64): close, not
+                # byte-identical
                 np.testing.assert_allclose(np.asarray(dh)[oh],
                                            np.asarray(ddv)[od], atol=1e-3)
-            # the wave's query block crossed once; bucket slabs reused it
-            assert snap["h2d_transfers"] > base["h2d_transfers"]
-            assert snap["h2d_transfers_saved"] > base["h2d_transfers_saved"]
+            # the wave's lanes went out in batched device_verify
+            # dispatches, and every byte sent is counted for queries
+            delta = {k: snap[k] - base[k] for k in (
+                "h2d_bytes", "query_h2d_bytes", "query_device_batches",
+                "query_distance_computations")}
+            assert delta["query_device_batches"] >= 1
+            assert delta["query_h2d_bytes"] == delta["h2d_bytes"] > 0
+            assert delta["query_distance_computations"] > 0
+
+
+def _query_index(tmp_path, x, eps, **kw):
+    """A small index whose candidate sets are complete (no Eq. 3
+    pruning, every bucket a candidate), so an ε-range answer is exact up
+    to float32 rounding."""
+    from repro.core import DiskJoinIndex, JoinConfig
+    cfg = JoinConfig(**{**dict(epsilon=eps, pad_align=64, num_buckets=16,
+                               memory_budget_bytes=1 << 20, prune=False,
+                               max_candidates=64, compute_mode="device",
+                               verify_batch=4), **kw})
+    return DiskJoinIndex.build(_store(x, tmp_path, "rq.bin"), cfg,
+                               str(tmp_path / "rq"))
+
+
+def _range_truth(x, Q, eps):
+    """Plain float64 brute-force ε-range answers: per query, the ids
+    within ε and their squared distances."""
+    x64, q64 = x.astype(np.float64), Q.astype(np.float64)
+    d2 = ((q64[:, None, :] - x64[None, :, :]) ** 2).sum(-1)
+    return [(np.flatnonzero(r <= eps * eps), r) for r in d2]
+
+
+def _as_dict(ids, dists):
+    assert ids.size == np.unique(ids).size  # no id twice in an answer
+    return dict(zip(ids.tolist(), np.asarray(dists, np.float64).tolist()))
+
+
+class TestQueryDeviceBatched:
+    """The device query path batches a wave's probed buckets into
+    ``device_verify`` lanes (``compute.DeviceQueryVerify``)."""
+
+    @pytest.fixture(scope="class")
+    def data(self):
+        from repro.data import clustered_vectors, epsilon_for_avg_neighbors
+        x = clustered_vectors(1500, 24, seed=9)
+        return x, epsilon_for_avg_neighbors(x, 12)
+
+    @staticmethod
+    def _wave(x, n, seed=0):
+        """n queries: indexed rows (hot: repeated) and perturbed rows."""
+        rng = np.random.default_rng(seed)
+        hot = x[rng.integers(0, 8, n)]
+        cold = x[rng.integers(0, x.shape[0], n)] + rng.normal(
+            scale=0.02, size=(n, x.shape[1])).astype(np.float32)
+        return np.where((np.arange(n) % 2 == 0)[:, None], hot, cold)
+
+    @pytest.mark.parametrize("use_pallas,wave", [
+        (False, 64), (True, 9)])
+    def test_matches_float64_reference(self, data, tmp_path, use_pallas,
+                                       wave):
+        """Same id sets as a float64 brute force, outside a band of
+        float32 rounding around ε; d² within float32's error. The device
+        forms d² = |q|² − 2 q·x + |x|² in float32, whose rounding is at
+        most a few units of 2⁻²⁴ times |q|² + |x|² per term summed over
+        the dimension: 64·2⁻²⁴·(|q|² + |x|²) bounds it with room."""
+        x, eps = data
+        Q = self._wave(x, wave)
+        with _query_index(tmp_path, x, eps, use_pallas=use_pallas) as idx:
+            got = idx.query_batch(Q)
+        sq = (x.astype(np.float64) ** 2).sum(1)
+        for q, (ids, d), (want, d2) in zip(Q, got,
+                                            _range_truth(x, Q, eps)):
+            tol = 64 * 2.0 ** -24 * ((q.astype(np.float64) ** 2).sum()
+                                     + sq)
+            band = np.abs(d2 - eps * eps) <= tol
+            ans = _as_dict(ids, d)
+            assert set(want) - set(np.flatnonzero(band)) <= set(ans)
+            assert set(ans) <= set(want) | set(np.flatnonzero(band))
+            for i, di in ans.items():
+                assert abs(di * di - d2[i]) <= tol[i], (i, di, d2[i])
+
+    @pytest.mark.parametrize("io_mode", ["sync", "prefetch"])
+    def test_matches_host_path(self, data, tmp_path, io_mode):
+        x, eps = data
+        Q = self._wave(x, 40, seed=1)
+        with _query_index(tmp_path, x, eps, io_mode=io_mode,
+                          prune=True, max_candidates=8) as idx:
+            host = idx.query_batch(Q, compute_mode="host")
+            dev = idx.query_batch(Q)
+        for (ih, dh), (idv, ddv) in zip(host, dev):
+            h, d = _as_dict(ih, dh), _as_dict(idv, ddv)
+            assert set(h) == set(d)
+            np.testing.assert_allclose([d[i] for i in h], list(h.values()),
+                                       atol=1e-3)
+
+    def test_warm_up_leaves_nothing_to_compile(self, data, tmp_path):
+        """After warm_queries(64), waves of 1, 7 and 64 queries, hot and
+        cold, lower and compile nothing."""
+        from repro.obs import compile_counts
+        from repro.serve import QueryScheduler
+
+        x, eps = data
+        with _query_index(tmp_path, x, eps) as idx:
+            idx.warm_queries(64)
+            before = compile_counts()
+            with QueryScheduler(idx, epsilon=eps, wave_size=64,
+                                max_wait_s=0.0) as sched:
+                for n in (1, 7, 64):
+                    futs = [sched.submit(q) for q in self._wave(x, n, n)]
+                    assert all(f.result(timeout=60)[0].size for f in futs)
+            direct = idx.query_batch(self._wave(x, 33, 2))
+            after = compile_counts()
+            snap = idx.pipeline_snapshot()
+        assert all(ids.size for ids, _ in direct)
+        assert snap["query_device_batches"] >= 3
+        for k in ("lowerings", "compiles"):
+            assert after[k] == before[k], (k, after["by_name"])
+
+    def test_overflow_redispatches_and_matches(self, data, tmp_path):
+        x, eps = data
+        Q = self._wave(x, 24, seed=3)
+        with _query_index(tmp_path, x, eps) as idx:
+            want = idx.query_batch(Q, compute_mode="host")
+            idx._query_lane_state().pair_cap = 1
+            got = idx.query_batch(Q)
+            snap = idx.pipeline_snapshot()
+            assert idx._query_lane_state().pair_cap > 1  # the raise sticks
+        assert snap["query_compact_overflows"] >= 1
+        for (ih, dh), (idv, ddv) in zip(want, got):
+            assert set(ih.tolist()) == set(idv.tolist())
+
+    def test_midwave_cancel_skips_reads(self, data, tmp_path):
+        x, eps = data
+        Q = self._wave(x, 6, seed=4)
+        with _query_index(tmp_path, x, eps, io_mode="sync") as idx:
+            plan = idx.plan_probes(Q)
+            buckets = {int(b) for p in plan for b in p}
+            out = idx.execute_probes(Q, plan, cancel=lambda qi: True)
+            snap = idx.pipeline_snapshot()
+            # query 0 cancelled: the others' answers are whole
+            full = idx.execute_probes(Q, plan)
+            part = idx.execute_probes(Q, plan, cancel=lambda qi: qi == 0)
+        assert snap["midwave_skipped_reads"] == len(buckets)
+        assert snap["query_device_batches"] == 0
+        assert all(ids.size == 0 for ids, _ in out)
+        for (a, _), (b, _) in zip(full[1:], part[1:]):
+            assert set(a.tolist()) == set(b.tolist())
+
+
+@pytest.mark.parametrize("m,n", [(64, 2048), (200, 256)])
+def test_verify_pairs_batch_unequal_rows(m, n):
+    """Query lanes pair m query rows with n bucket rows: each operand is
+    padded to its own block multiple and sliced back."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+
+    rng = np.random.default_rng(m + n)
+    u = rng.normal(size=(2, m, 16)).astype(np.float32)
+    v = (u[:, rng.integers(0, m, n)] + rng.normal(
+        scale=0.2, size=(2, n, 16))).astype(np.float32)
+    d2p, mp = kops.verify_pairs_batch(jnp.asarray(u), jnp.asarray(v), 1.2,
+                                      use_pallas=True)
+    d2r, mr = kops.verify_pairs_batch(jnp.asarray(u), jnp.asarray(v), 1.2)
+    assert d2p.shape == mp.shape == (2, m, n)
+    np.testing.assert_allclose(np.asarray(d2p), np.asarray(d2r),
+                               rtol=1e-5, atol=1e-4)
+    assert np.asarray(mr).any()
+    agree = np.asarray(mp) == np.asarray(mr)
+    near = np.abs(np.asarray(d2r) - 1.2 ** 2) < 1e-4
+    assert (agree | near).all()
 
 
 # ---------------------------------------------------------------------------
