@@ -51,7 +51,8 @@ re-compacted from its still-resident d2/mask at the next power of two
 (the kernel output was sized too small, not wrong), and the larger
 capacity sticks for later batches. ``DeviceQueryVerify`` runs a query
 wave's probed buckets through the same ``device_verify``; the
-distributed path keeps one capacity per lane (``compact_pairs``).
+distributed path (``core/distributed.verify_edges_compact``) runs
+``compact_batch`` on each chip's own lanes, one capacity a chip.
 """
 from __future__ import annotations
 

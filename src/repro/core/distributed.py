@@ -7,8 +7,9 @@ Mapping of the paper's single-box design onto a pod:
                       assembled by the host under the same Belady policy,
                       then placed sharded over the ``data`` axis
   edge tasks        → sharded over ``data``: each device verifies its slice
-                      of the window's edges; remote buckets arrive via the
-                      gather XLA inserts for cross-shard ``jnp.take``
+                      of the window's edges against the window slab, which
+                      every device holds; in device mode each compacts its
+                      own lanes' pairs (``verify_edges_compact``)
   verify kernel     → vmapped pairwise-L2 threshold (Pallas on TPU)
 
 Supersteps inherit the Gorder locality: consecutive windows share most of
@@ -23,6 +24,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import ordering
 from repro.core.executor import PAD_COORD
@@ -49,20 +51,44 @@ def verify_edges(slab: jax.Array, edges: jax.Array, eps2: float):
     return jnp.sum(mask, axis=(1, 2)), mask, d2
 
 
-@partial(jax.jit, static_argnames=("eps2", "k_cap"))
+@partial(jax.jit, static_argnames=("eps2", "k_cap", "mesh"))
 def verify_edges_compact(slab: jax.Array, edges: jax.Array, na: jax.Array,
                          nb: jax.Array, intra: jax.Array, eps2: float,
-                         k_cap: int):
+                         k_cap: int, mesh: jax.sharding.Mesh | None = None):
     """Compacted variant (``compute_mode="device"``): instead of shipping
-    the full (E, cap, cap) mask back to the host, pairs are compacted
-    on-device (``repro.compute.compact_pairs``) — D2H shrinks from
-    E·cap² bytes to E·(1 + 3·k_cap) values. ``na``/``nb`` carry the
-    live-row counts (0 masks a padding lane out entirely)."""
-    from repro.compute import compact_pairs
-    u = jnp.take(slab, edges[:, 0], axis=0)
-    v = jnp.take(slab, edges[:, 1], axis=0)
-    d2 = jax.vmap(ref.pairwise_l2)(u, v)
-    return compact_pairs(d2, d2 <= eps2, na, nb, intra, k_cap)
+    the full (E, cap, cap) mask back to the host, each chip compacts its
+    own lanes' pairs on-device into one pool of ``k_cap`` pairs
+    (``repro.compute.compact_batch``), so D2H shrinks from E·cap² bytes
+    to E + 3·k_cap·chips values. ``na``/``nb`` carry the live-row counts
+    (0 masks a padding lane out entirely).
+
+    With ``mesh`` the body runs per chip under ``shard_map`` over
+    ``data``: the slab is replicated, the lanes split, and no running
+    count crosses chips. Without, all lanes share one pool. Returns
+    (counts (E,), rows, cols, d2), the last three (E, k_cap / L) with
+    L = E / chips, ``k_cap`` a multiple of L: a chip's L rows hold its
+    pool, lane e's pairs at the chip's exclusive cumsum of ``counts``,
+    each lane in ``np.nonzero`` order. Counts stay exact past ``k_cap``.
+    """
+    from repro.compute import compact_batch
+
+    def per_chip(slab, edges, na, nb, intra):
+        u = jnp.take(slab, edges[:, 0], axis=0)
+        v = jnp.take(slab, edges[:, 1], axis=0)
+        d2 = jax.vmap(ref.pairwise_l2)(u, v)     # (L, cap, cap)
+        lanes = edges.shape[0]
+        if k_cap % lanes:
+            raise ValueError(f"k_cap {k_cap} is not a multiple of the "
+                             f"{lanes} lanes a chip")
+        counts, *pool = compact_batch(d2, d2 <= eps2, na, nb, intra, k_cap)
+        return (counts, *(a.reshape(lanes, -1) for a in pool))
+
+    if mesh is None:
+        return per_chip(slab, edges, na, nb, intra)
+    split = P("data")
+    return jax.shard_map(per_chip, mesh=mesh,
+                         in_specs=(P(), split, split, split, split),
+                         out_specs=(split,) * 4)(slab, edges, na, nb, intra)
 
 
 def with_auto_axes(mesh: jax.sharding.Mesh) -> jax.sharding.Mesh:
@@ -167,7 +193,10 @@ class DistributedJoin:
         self._dev_pool = (DeviceSlabPool() if config.compute_mode == "device"
                           else None)
         self._next_pow2 = next_pow2
-        self._pair_cap = min(next_pow2(max(1024, 8 * self.cap)),
+        self._shards = self.mesh.shape["data"] if self.mesh is not None else 1
+        # the pair capacity a chip's lanes share in a dispatch: two lanes
+        # a chip at 8·cap pairs each
+        self._pair_cap = min(next_pow2(max(1024, 16 * self.cap)),
                              self.cap * self.cap)
 
     def _read_padded(self, b: int) -> tuple[np.ndarray, np.ndarray, int]:
@@ -231,11 +260,10 @@ class DistributedJoin:
         (bounded recompiles) and, under a mesh, to a shard multiple; pad
         lanes point at slab 0 and their results are dropped. Yields
         (chunk edges, padded device edges)."""
-        shards = self.mesh.shape["data"] if sharding is not None else 1
-        chunk = self.config.verify_batch * shards
+        chunk = self.config.verify_batch * self._shards
         for c0 in range(0, edges.shape[0], chunk):
             ce = edges[c0:c0 + chunk]
-            Ep = _round_up(self._next_pow2(ce.shape[0]), shards)
+            Ep = _round_up(self._next_pow2(ce.shape[0]), self._shards)
             edges_dev = jnp.asarray(np.concatenate(
                 [ce, np.zeros((Ep - ce.shape[0], 2), ce.dtype)]))
             if sharding is not None:
@@ -244,10 +272,10 @@ class DistributedJoin:
 
     def _dispatch_compact(self, slab, ce, edges_dev, entries, eps2):
         """Issue the compacted verify of one chunk (async) at the current
-        pair capacity. Pad lanes carry na = nb = 0 so the compaction
-        masks them out entirely. The handle keeps the capacity the chunk
-        was sent at: a raise by an earlier chunk's overflow must not
-        hide this one's."""
+        pair capacity a chip, rounded up to a multiple of its lanes. Pad
+        lanes carry na = nb = 0 so the compaction masks them out
+        entirely. The handle keeps the capacity the chunk was sent at: a
+        raise by an earlier chunk's overflow must not hide this one's."""
         Ep, E = edges_dev.shape[0], ce.shape[0]
         rowc = np.array([e[2] for e in entries], np.int32)
         na = np.zeros(Ep, np.int32)
@@ -258,35 +286,46 @@ class DistributedJoin:
         intra[:E] = ce[:, 0] == ce[:, 1]
         args = (slab, edges_dev, jnp.asarray(na), jnp.asarray(nb),
                 jnp.asarray(intra))
-        k_cap = self._pair_cap
-        return ce, args, k_cap, verify_edges_compact(*args, eps2, k_cap)
+        k_cap = _round_up(self._pair_cap, Ep // self._shards)
+        return ce, args, k_cap, verify_edges_compact(*args, eps2, k_cap,
+                                                     self.mesh)
 
     def _extract_compact(self, handles, entries, eps2):
         """Fetch a superstep's compacted pairs (+ distances), chunk by
-        chunk in edge order. A chunk whose densest edge holds more pairs
-        than the capacity it was sent at is re-sent at the next pow2;
-        the raise is sticky for later chunks and steps."""
+        chunk in edge order. A chunk whose fullest chip holds more pairs
+        than the capacity it was sent at is re-sent at the next pow2 of
+        that chip's total; the raise is sticky for later chunks and
+        steps. Returns (pairs, dists, the largest capacity read, the
+        largest chip total)."""
         res, res_d = [], []
+        cap_read = chip_max = 0
         for ce, args, k_cap, out in handles:
             counts = np.asarray(out[0])
-            top = int(counts[:ce.shape[0]].max())
+            lanes = counts.shape[0] // self._shards
+            by_chip = counts.reshape(-1, lanes).astype(np.int64)
+            top = int(by_chip.sum(axis=1).max())
             if top > k_cap:
+                # counts are exact at any capacity; only the pools grow
                 self.overflows += 1
-                self._pair_cap = max(self._pair_cap, min(
-                    self._next_pow2(top), self.cap * self.cap))
-                out = verify_edges_compact(*args, eps2, self._pair_cap)
-                counts = np.asarray(out[0])
-            rows_c = np.asarray(out[1])
-            cols_c = np.asarray(out[2])
-            dist_c = np.sqrt(np.asarray(out[3]))
+                self._pair_cap = max(self._pair_cap, self._next_pow2(top))
+                k_cap = _round_up(self._pair_cap, lanes)
+                out = verify_edges_compact(*args, eps2, k_cap, self.mesh)
+            cap_read, chip_max = max(cap_read, k_cap), max(chip_max, top)
+            # a chip's lanes' rows hold its pool; lane e's pairs start at
+            # the chip's running count of the lanes before e
+            rows_c, cols_c, d2_c = (np.asarray(o).reshape(self._shards, -1)
+                                    for o in out[1:])
+            dist_c = np.sqrt(d2_c)
+            start = (np.cumsum(by_chip, axis=1) - by_chip).ravel()
             for ei, (a, b) in enumerate(ce):
                 k = int(counts[ei])
                 if k:
+                    chip, s = ei // lanes, start[ei]
                     ida, idb = entries[a][1], entries[b][1]
-                    res.append(np.stack([ida[rows_c[ei, :k]],
-                                         idb[cols_c[ei, :k]]], axis=1))
-                    res_d.append(dist_c[ei, :k].astype(np.float32))
-        return res, res_d
+                    res.append(np.stack([ida[rows_c[chip, s:s + k]],
+                                         idb[cols_c[chip, s:s + k]]], axis=1))
+                    res_d.append(dist_c[chip, s:s + k].astype(np.float32))
+        return res, res_d, cap_read, chip_max
 
     @staticmethod
     def _extract_host(handles, entries):
@@ -361,6 +400,7 @@ class DistributedJoin:
                 self.mesh, jax.sharding.PartitionSpec("data"))
 
         dc = 0
+        job_cap = job_chip_max = 0
         tracer = get_tracer()
         for si, step in enumerate(steps):
             if si < start_si:
@@ -411,11 +451,15 @@ class DistributedJoin:
                 else entries[a][2] * (entries[a][2] - 1) // 2
                 for a, b in edges)
             if self._dev_pool is not None:
-                step_pairs, step_dists = self._extract_compact(
-                    handles, entries, eps2)
+                step_pairs, step_dists, cap_read, chip_max = \
+                    self._extract_compact(handles, entries, eps2)
+                step_span.set(pair_cap=cap_read, chip_pairs_max=chip_max)
+                job_cap = max(job_cap, cap_read)
+                job_chip_max = max(job_chip_max, chip_max)
             else:
                 step_pairs, step_dists = self._extract_host(handles,
                                                             entries)
+            step_span.set(pairs=sum(len(p) for p in step_pairs))
             pairs_out.extend(step_pairs)
             dists_out.extend(step_dists)
             if checkpointer is not None:
@@ -457,4 +501,6 @@ class DistributedJoin:
             info["device_slab_hits"] = self._dev_pool.hits
             info["h2d_bytes"] = self._dev_pool.h2d_bytes
             info["compact_overflows"] = self.overflows
+            info["pair_cap"] = job_cap
+            info["chip_pairs_max"] = job_chip_max
         return pairs, info

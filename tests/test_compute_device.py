@@ -3,6 +3,11 @@ matrix (pairs AND distances byte-identical), device slab-pool residency
 accounting (transfers == residencies, not edges), on-device compaction
 vs np.nonzero, verify_batch config, batched Pallas dispatch, distributed
 device mode + next-window prefetch, and the device query path."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -422,7 +427,8 @@ class TestBatchedKernel:
 # distributed join: device slabs + next-window prefetch
 # ---------------------------------------------------------------------------
 class TestDistributedDevice:
-    def _setup(self, tmp_path, budget):
+    @staticmethod
+    def _setup(tmp_path, budget):
         from repro.core import JoinConfig, build_bucket_graph, bucketize
         from repro.data import clustered_vectors, epsilon_for_avg_neighbors
 
@@ -464,25 +470,84 @@ class TestDistributedDevice:
         # prefetched loads are a subset of total loads (never extra I/O)
         assert info["prefetched_buckets"] <= info["host_loads"]
 
-    def test_overflow_in_one_chunk_keeps_later_chunks_whole(self, tmp_path):
+    @pytest.mark.parametrize("chips", [1, 4])
+    def test_overflow_in_one_chunk_keeps_later_chunks_whole(self, tmp_path,
+                                                            chips):
         """A superstep's chunks are all sent before any is read. When one
         overflows and raises the pair capacity, a later chunk sent at the
-        old capacity must be re-sent, not read truncated."""
+        old capacity must be re-sent, not read truncated. On four chips
+        (a subprocess with four CPU devices) each chip's lanes share one
+        pool, and an overflow is judged on the fullest chip."""
+        if chips == 1:
+            _overflow_in_one_chunk(tmp_path)
+            return
+        code = textwrap.dedent(f"""
+            import os, pathlib, sys
+            os.environ['XLA_FLAGS'] = \
+                '--xla_force_host_platform_device_count={chips}'
+            sys.path[:0] = ['src', 'tests']
+            import jax
+            from test_compute_device import _overflow_in_one_chunk
+            _overflow_in_one_chunk(pathlib.Path({str(tmp_path)!r}),
+                                   jax.make_mesh(({chips},), ('data',)))
+            print('OVERFLOW-OK')
+        """)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        p = subprocess.run([sys.executable, "-c", code], cwd=root,
+                           capture_output=True, text=True, timeout=600)
+        assert p.returncode == 0, p.stderr[-3000:]
+        assert "OVERFLOW-OK" in p.stdout
+
+    def test_superstep_spans_carry_the_pair_counters(self, tmp_path):
+        """Each ``dist.superstep`` span records its pairs, the per-chip
+        capacity it was read at and its fullest chip; ``info`` keeps the
+        job's largest of both, the chip total within the capacity."""
         from repro.core import JoinConfig
         from repro.core.distributed import DistributedJoin
+        from repro.obs import trace_session
 
         bs, meta, graph, cfg = self._setup(tmp_path, 150_000)
-        cfg["verify_batch"] = 1  # one edge per chunk
-        ph, ih = DistributedJoin(bs, meta, JoinConfig(**cfg)).run(graph)
         dj = DistributedJoin(bs, meta,
                              JoinConfig(compute_mode="device", **cfg))
-        dj._pair_cap = 1  # the first chunk to hold two pairs overflows
-        pd, idv = dj.run(graph)
-        assert idv["verify_dispatches"] > 3 * idv["supersteps"]
-        assert ih["verify_dispatches"] == idv["verify_dispatches"]
-        assert idv["compact_overflows"] >= 1
-        assert np.array_equal(ph, pd)
-        assert np.array_equal(ih["dists"], idv["dists"])
+        dj._pair_cap = 2  # some superstep overflows and re-sends
+        with trace_session() as tr:
+            _, info = dj.run(graph)
+        steps = [e["args"] for e in tr.events()
+                 if e["ph"] == "X" and e["name"] == "dist.superstep"]
+        assert len(steps) == info["supersteps"]
+        assert {"pairs", "pair_cap", "chip_pairs_max"} <= set(steps[0])
+        assert sum(a["pairs"] for a in steps) == info["watermark_rows"] > 0
+        assert all(a["chip_pairs_max"] <= a["pair_cap"] for a in steps)
+        assert info["compact_overflows"] >= 1
+        assert 0 < info["chip_pairs_max"] <= info["pair_cap"]
+        assert info["chip_pairs_max"] == max(a["chip_pairs_max"]
+                                             for a in steps)
+        assert info["pair_cap"] == max(a["pair_cap"] for a in steps)
+
+
+def _overflow_in_one_chunk(tmp_path, mesh=None):
+    """``test_overflow_in_one_chunk_keeps_later_chunks_whole``'s run, on
+    ``mesh`` if given: one edge a chip per chunk, the device join started
+    at a pair capacity of 1, against ``compute_mode="host"``. The budget
+    grows with the chips, so that a window still spans several chunks."""
+    from repro.core import JoinConfig
+    from repro.core.distributed import DistributedJoin
+
+    chips = 1 if mesh is None else mesh.shape["data"]
+    bs, meta, graph, cfg = TestDistributedDevice._setup(tmp_path,
+                                                        150_000 * chips)
+    cfg["verify_batch"] = 1  # one edge a chip per chunk
+    ph, ih = DistributedJoin(bs, meta, JoinConfig(**cfg),
+                             mesh=mesh).run(graph)
+    dj = DistributedJoin(bs, meta, JoinConfig(compute_mode="device", **cfg),
+                         mesh=mesh)
+    dj._pair_cap = 1  # the first chunk to hold two pairs overflows
+    pd, idv = dj.run(graph)
+    assert idv["verify_dispatches"] > 3 * idv["supersteps"]
+    assert ih["verify_dispatches"] == idv["verify_dispatches"]
+    assert idv["compact_overflows"] >= 1
+    assert np.array_equal(ph, pd)
+    assert np.array_equal(ih["dists"], idv["dists"])
 
 
 # ---------------------------------------------------------------------------

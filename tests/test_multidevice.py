@@ -158,6 +158,150 @@ def test_distributed_join_chunked_dispatch_matches_one_chip_join():
     assert "CHUNKED-OK" in out
 
 
+# Each case is one dispatch of 8 lanes, two a chip, on a 4-device data
+# mesh, against ``compact_pairs`` run lane by lane on the d² of
+# compute_mode="host"'s program (``verify_edges``, the same
+# ``ref.pairwise_l2``):
+# - empty_chip: chip 1's lanes pair live rows of buckets ε apart;
+# - pad_lane: lane 5 is a pad lane (na = nb = 0), as ``_edge_chunks`` pads;
+# - intra_lane: lane 6 verifies a bucket against itself;
+# - chip_overflow: chip 3 holds more pairs than ``k_cap``, the others fit.
+_POOL_CASES = """
+    import json
+    import jax, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.compute import compact_pairs
+    from repro.core.distributed import (verify_edges, verify_edges_compact,
+                                        with_auto_axes)
+
+    mesh = with_auto_axes(jax.make_mesh((4,), ('data',)))
+    rng = np.random.default_rng(3)
+    W, cap, d, E, L = 6, 16, 4, 8, 2
+    slab = rng.normal(size=(W, cap, d)).astype(np.float32)
+    slab[4] += 100.0                      # far from every other bucket
+    slab[5] *= 0.01                       # every row pair within eps
+    eps2 = 2.0
+    base = dict(edges=[[0, 1], [1, 2], [2, 3], [3, 0], [0, 2], [1, 3],
+                       [2, 0], [3, 1]],
+                na=[cap, 11, cap, 9, cap, cap, 13, cap],
+                nb=[cap, cap, 10, cap, 12, cap, cap, 7],
+                intra=[False] * E)
+    cases = {
+        'empty_chip': dict(edges={2: [0, 4], 3: [4, 1]}),
+        'pad_lane': dict(edges={5: [0, 0]}, na={5: 0}, nb={5: 0}),
+        'intra_lane': dict(edges={6: [2, 2]}, intra={6: True}),
+        'chip_overflow': dict(edges={6: [5, 5], 7: [5, 5]},
+                              intra={6: True}),
+    }
+    rep, dat = NamedSharding(mesh, P()), NamedSharding(mesh, P('data'))
+    for name, change in cases.items():
+        arg = {k: list(v) for k, v in base.items()}
+        for k, by_lane in change.items():
+            for e, v in by_lane.items():
+                arg[k][e] = v
+        edges = np.asarray(arg['edges'], np.int32)
+        na, nb = (np.asarray(arg[k], np.int32) for k in ('na', 'nb'))
+        intra = np.asarray(arg['intra'])
+        args = (jax.device_put(slab, rep), jax.device_put(edges, dat),
+                jax.device_put(na, dat), jax.device_put(nb, dat),
+                jax.device_put(intra, dat))
+        # the d² of compute_mode="host"'s program, the same ref.pairwise_l2
+        d2 = verify_edges(*args[:2], eps2)[2]
+        want = [jax.device_get(compact_pairs(
+            d2[e:e + 1], d2[e:e + 1] <= eps2, na[e:e + 1], nb[e:e + 1],
+            intra[e:e + 1], cap * cap)) for e in range(E)]
+        want_n = np.array([int(w[0][0]) for w in want])
+        chip_n = want_n.reshape(4, L).sum(axis=1)
+        # the capacity the chunk is sent at: fits every chip but the
+        # fullest in the overflow case, then the re-send at its total
+        fits = int(np.sort(chip_n)[-2 if name == 'chip_overflow' else -1])
+        k_cap = fits + 1 + (-(fits + 1) % L)
+        if name == 'chip_overflow':
+            assert chip_n.max() > k_cap
+        hlo = verify_edges_compact.lower(*args, eps2, k_cap,
+                                         mesh).compile().as_text()
+        collectives = [c for c in ('all-reduce', 'all-gather',
+                                   'reduce-scatter', 'collective-permute')
+                       if c in hlo]
+        def pool_of(out, e):  # lane e's rows, cols, d2 in its chip's pool
+            chip = e // L
+            off = int(out[0][chip * L:e].sum())
+            k = int(out[0][e])
+            return [o[chip * L:(chip + 1) * L].ravel()[off:off + k]
+                    for o in out[1:]]
+
+        def lane_matches(out, e):
+            got = pool_of(out, e)
+            ref_lane = [w[0, :want_n[e]] for w in want[e][1:]]
+            return bool(int(out[0][e]) == want_n[e]
+                        and np.array_equal(got[0], ref_lane[0])
+                        and np.array_equal(got[1], ref_lane[1])
+                        and np.array_equal(got[2].view(np.uint32),
+                                           ref_lane[2].view(np.uint32)))
+
+        sent = [k_cap]
+        out = jax.device_get(verify_edges_compact(*args, eps2, k_cap, mesh))
+        first_counts = out[0].tolist()
+        cut = [int(c) for c in np.flatnonzero(
+            out[0].reshape(4, L).sum(axis=1) > k_cap)]
+        # before any re-send: the chips that fit are whole, and a cut
+        # chip's pool holds the first k_cap of its lanes' pairs
+        whole_first = [lane_matches(out, e) for e in range(E)
+                       if e // L not in cut]
+        prefix_first = [bool(np.array_equal(
+            out[1][c * L:(c + 1) * L].ravel(), np.concatenate(
+                [want[e][1][0, :want_n[e]]
+                 for e in range(c * L, (c + 1) * L)])[:k_cap]))
+            for c in cut]
+        if cut:
+            k_cap = 1 << (int(chip_n.max()) - 1).bit_length()
+            k_cap += -k_cap % L
+            sent.append(k_cap)
+            out = jax.device_get(verify_edges_compact(*args, eps2, k_cap,
+                                                      mesh))
+        shape = [list(o.shape) for o in out]
+        same = [lane_matches(out, e) for e in range(E)]
+        print(json.dumps(dict(case=name, same=same, cut=cut, sent=sent,
+                              whole_first=whole_first,
+                              prefix_first=prefix_first,
+                              shape=shape, lanes_n=want_n.tolist(),
+                              first_counts=first_counts,
+                              collectives=collectives)))
+"""
+
+
+@pytest.fixture(scope="module")
+def pooled_dispatches() -> dict:
+    out = _run(_POOL_CASES, devices=4)
+    return {r["case"]: r for r in map(json.loads, out.strip().splitlines())}
+
+
+@pytest.mark.parametrize("case", ["empty_chip", "pad_lane", "intra_lane",
+                                  "chip_overflow"])
+def test_verify_edges_compact_pools_each_chip(pooled_dispatches, case):
+    """Each chip compacts its own lanes into one pool: every lane's pairs
+    and d² bits equal the per-lane ``compact_pairs`` on the same d², in
+    order; a chip past ``k_cap`` keeps exact counts and leaves the other
+    chips whole, and the re-sent dispatch is whole; the program exchanges
+    nothing between chips."""
+    r = pooled_dispatches[case]
+    assert r["collectives"] == []
+    assert r["first_counts"] == r["lanes_n"]   # exact even past k_cap
+    assert all(r["same"]) and all(r["whole_first"]), r
+    assert r["shape"] == [[8]] + [[8, r["sent"][-1] // 2]] * 3
+    if case == "chip_overflow":
+        assert r["cut"] == [3] and len(r["sent"]) == 2
+        assert len(r["whole_first"]) == 6 and r["prefix_first"] == [True]
+    else:
+        assert r["cut"] == [] and len(r["sent"]) == 1
+    if case == "empty_chip":
+        assert r["lanes_n"][2:4] == [0, 0]
+    if case == "pad_lane":
+        assert r["lanes_n"][5] == 0
+    if case == "intra_lane":
+        assert 0 < r["lanes_n"][6] < 16 * 15 // 2
+
+
 def test_chip_smoke_four_chip_rehearsal():
     # the CPU rehearsal of `chip_smoke.py --chips 4`: DistributedJoin on a
     # 4-device data mesh against the one-chip join, and float64 truth

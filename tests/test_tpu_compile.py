@@ -90,8 +90,13 @@ def test_distributed_verify_compiles_on_four_chip_mesh(topo):
     compiled = verify_edges_compact.lower(
         _spec((48, CAP, DIM), whole), _spec((edges, 2), split, jnp.int32),
         _spec((edges,), split, jnp.int32), _spec((edges,), split, jnp.int32),
-        _spec((edges,), split, jnp.bool_), 0.09, 1024).compile()
+        _spec((edges,), split, jnp.bool_), 0.09, 1024, mesh).compile()
     per_device = compiled.memory_analysis().temp_size_in_bytes
-    # each chip holds the temporaries of its own quarter of the edges
+    # each chip holds the temporaries of its own quarter of the edges,
+    # and compacts them with no running count across chips
     assert per_device < 4 * 2 ** 30, per_device
+    text = compiled.as_text()
+    assert not any(c in text for c in ("all-reduce", "all-gather",
+                                       "reduce-scatter",
+                                       "collective-permute")), text[:2000]
     assert np.prod(list(mesh.shape.values())) == 4
